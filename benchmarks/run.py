@@ -20,6 +20,7 @@ import os
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import telemetry
 
 from . import (bench_analytical_gap, bench_battery_capacity,
@@ -72,6 +73,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="tiny grids, API-regression signal only (CI)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         common.SMOKE = True
     stamp = datetime.datetime.now(datetime.timezone.utc).isoformat(

@@ -88,9 +88,9 @@ Kernel backends
     trace_range/510.  Both are below trace calibration uncertainty; pass
     trace_store='f32' to the kernel for exact inputs.
 
-  Pallas kernels themselves run in interpret mode iff the backend is CPU
-  (kernels/ops.resolved_interpret; `STEAM_PALLAS_INTERPRET` overrides),
-  resolved per call — never pinned at import.
+  Pallas kernels themselves run in interpret mode iff the platform the
+  call runs on is CPU (kernels/ops.resolved_interpret), resolved per call
+  — never pinned at import.
 """
 from __future__ import annotations
 
@@ -980,14 +980,12 @@ def _simulate_megakernel(state0: SimState, inputs: StepInputs,
     # trace store has no slot for the derate series)
     if (cfg.use_pallas and not cfg.collect_series and not cfg.probes.enabled
             and not cfg.resilience.enabled):
-        from repro.kernels import fused_step as fused_mod
-        from repro.kernels.ops import resolved_interpret
-        totals = fused_mod.fused_facility_totals(
+        from repro.kernels import ops as pc_ops
+        totals = pc_ops.facility_totals(
             it_series, inputs.ci, inputs.wet_bulb_c, inputs.price,
             inputs.price_lo, inputs.price_hi, inputs.pv_cf,
             inputs.batt_threshold, inputs.ci_rising, cfg,
-            trace_store=cfg.trace_store, interpret=resolved_interpret(),
-            **chain_kwargs)
+            trace_store=cfg.trace_store, **chain_kwargs)
         final = _merge_facility_totals(final, totals, cfg, dyn)
         return final, None
     with telemetry_mod.stage_scope("megakernel.facility"):

@@ -156,14 +156,14 @@ the enable flag itself switches the compiled pipeline.
 """
 from __future__ import annotations
 
+import math
 import os
 import warnings
 from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .config import SimConfig
 from .engine import StepInputs, simulate
@@ -724,8 +724,8 @@ class ScenarioGrid:
 
     def _shardings(self, mesh, red=None):
         """(in_shardings, out_sharding, lead, repl) for this grid on `mesh`."""
-        spec = _mesh_spec(mesh)
-        lead = NamedSharding(mesh, spec)
+        axes = _lead_axes(mesh)
+        lead = NamedSharding(mesh, P(axes))
         repl = NamedSharding(mesh, P())
         in_sh = tuple(
             jax.tree.map(lambda _: lead if i == 0 else repl, p)
@@ -733,39 +733,30 @@ class ScenarioGrid:
         n = len(self.shape)  # swept dims only; per_region trailing axes of a
         # fleet grid are shorter than the spec and stay replicated
         if red is None:
-            out_spec = P(*(spec + tuple(None for _ in range(n - 1))))
+            out_spec = P(axes, *(None,) * (n - 1))
         elif red[1] == 0:  # the sharded axis is reduced away -> replicated
             out_spec = P(*(None,) * (n - 1))
         else:
-            out_spec = P(*(spec + tuple(None for _ in range(n - 2))))
+            out_spec = P(axes, *(None,) * (n - 2))
         return in_sh, NamedSharding(mesh, out_spec), lead, repl
 
     def _run_sharded(self, fn, payloads, mesh, chunk_size, red=None):
         # chunk_size arrives already rounded to a device multiple
         # (_round_chunk_to_mesh in `run`), so the leading-axis reduce guard
         # and the actual chunking agree on what gets split
-        in_sh, out_sh, lead, repl = self._shardings(mesh, red)
+        in_sh, out_sh, lead, repl = self._shardings(_auto_mesh(mesh), red)
         jfn = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
 
         def run_chunk(p0):
             args = (jax.device_put(p0, lead),) + tuple(
                 jax.device_put(p, repl) for p in payloads[1:])
-            with mesh:
-                return jfn(*args)
+            return jfn(*args)
 
         if chunk_size is None or self.axes[0].length <= chunk_size:
             return run_chunk(payloads[0])
         return _concat_chunks(
             [run_chunk(_slice_lead(payloads[0], s, chunk_size))
              for s in range(0, self.axes[0].length, chunk_size)])
-
-    def _mesh_lead_devices(self, mesh) -> int:
-        """Device count along the mesh axes the leading dim shards over."""
-        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        ndev = 1
-        for a in (_mesh_spec(mesh)[0] or ()):
-            ndev *= sizes[a]
-        return ndev
 
     def shard_map_callable(self, tasks: TaskTable, hosts: HostTable,
                            cfg: SimConfig, ci_trace=None, *, mesh=None,
@@ -774,7 +765,7 @@ class ScenarioGrid:
 
         The returned callable places each leading-axis chunk of
         ``lead / n_devices`` grid cells on its own device via
-        :func:`jax.experimental.shard_map.shard_map` — every device runs
+        :func:`jax.shard_map` — every device runs
         the SAME per-shard program on its local block, with no collectives
         (grid cells are independent), so weak scaling (cells ∝ devices)
         holds the per-device working set and per-device wall time constant.
@@ -790,10 +781,9 @@ class ScenarioGrid:
         if self.axes[0].kind == "region":
             raise ValueError("cannot shard a grid whose leading axis is the "
                              "region_axis: add a swept leading axis")
-        if mesh is None:
-            mesh = jax.make_mesh((jax.device_count(),), ("data",))
-        spec = _mesh_spec(mesh)
-        ndev = self._mesh_lead_devices(mesh)
+        mesh = _auto_mesh(mesh)
+        spec = P(_lead_axes(mesh))
+        ndev = _lead_devices(mesh)
         lead = self.axes[0].length
         if lead % ndev:
             raise ValueError(
@@ -803,8 +793,8 @@ class ScenarioGrid:
         fn = self.grid_fn(tasks, hosts, cfg, ci_trace)
         n_pay = len(self.axes)
         in_specs = tuple(spec if i == 0 else P() for i in range(n_pay))
-        sm = shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=spec,
-                       check_rep=False)
+        sm = jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=spec,
+                           check_vma=False)
         jfn = jax.jit(sm, donate_argnums=(0,) if donate else ())
         lead_sh = NamedSharding(mesh, spec)
         repl_sh = NamedSharding(mesh, P())
@@ -833,8 +823,7 @@ class ScenarioGrid:
         """
         self._check_cfg(cfg)
         self._check_tasks(tasks)
-        if mesh is None:
-            mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        mesh = _auto_mesh(mesh)
         with telemetry_mod.span("grid.build", shape=str(self.shape),
                                 executor="shard_map"):
             call = self.shard_map_callable(tasks, hosts, cfg, ci_trace,
@@ -852,7 +841,7 @@ class ScenarioGrid:
             rec.extra["n_scenarios"] = int(self.n_scenarios)
             rec.mesh = {"axis_names": [str(a) for a in mesh.axis_names],
                         "shape": [int(s) for s in mesh.devices.shape]}
-            ndev = self._mesh_lead_devices(mesh)
+            ndev = _lead_devices(mesh)
             rec.chunk = {
                 "chunk_size": int(self.axes[0].length // ndev),
                 "n_chunks": int(ndev),
@@ -890,15 +879,37 @@ class ScenarioGrid:
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.payloads())
         if mesh is None:
             return jax.jit(fn).lower(*abstract)
-        in_sh, out_sh, _, _ = self._shardings(mesh, red)
+        in_sh, out_sh, _, _ = self._shardings(_auto_mesh(mesh), red)
         jfn = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
-        with mesh:
-            return jfn.lower(*abstract)
+        return jfn.lower(*abstract)
 
 
-def _mesh_spec(mesh) -> P:
-    axes = [a for a in ("pod", "data") if a in mesh.axis_names]
-    return P(tuple(axes))
+def _auto_mesh(mesh=None) -> Mesh:
+    """The mesh a grid runs on, with every axis in `Auto` mode.
+
+    Grid cells are independent, so placement is fully described by the
+    in/out shardings and no context mesh is needed.  `jax.make_mesh`
+    returns `Explicit` axes, which would put shardings into the traced
+    types of every op inside the cells; the grid asks for none of that.
+    None means one 'data' axis over all devices."""
+    if mesh is None:
+        return jax.make_mesh((jax.device_count(),), ("data",),
+                             axis_types=(AxisType.Auto,))
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
+def _lead_axes(mesh) -> tuple[str, ...]:
+    """The mesh axes the leading grid dim shards over, as a plain tuple
+    (PartitionSpec normalizes a 1-tuple entry to the bare name)."""
+    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+
+
+def _lead_devices(mesh) -> int:
+    """Device count along the mesh axes the leading dim shards over."""
+    return math.prod(mesh.shape[a] for a in _lead_axes(mesh))
 
 
 def _round_chunk_to_mesh(mesh, chunk_size: int) -> int:
@@ -906,10 +917,7 @@ def _round_chunk_to_mesh(mesh, chunk_size: int) -> int:
     the mesh devices; round the chunk up to a device multiple (the total
     leading length must divide too, as in any sharded sweep — then every
     chunk including the tail stays divisible)."""
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    ndev = 1
-    for a in (_mesh_spec(mesh)[0] or ()):
-        ndev *= sizes[a]
+    ndev = _lead_devices(mesh)
     return max(ndev, -(-chunk_size // ndev) * ndev)
 
 

@@ -37,11 +37,13 @@ def _per_host_sum(vals, seg, h: int):
 
     Exact for integer-valued inputs (core/GPU counts) in any order; for
     float-weighted inputs the summation order differs from segment_sum by
-    ULP-level rounding only.
+    ULP-level rounding only.  HIGHEST precision keeps the f32 values f32 on
+    the TPU's matrix unit, whose default rounds operands to bf16.
     """
     if h <= _MATMUL_MAX_HOSTS:
         onehot = (seg[None, :] == jnp.arange(h, dtype=seg.dtype)[:, None])
-        return onehot.astype(vals.dtype) @ vals
+        return jnp.matmul(onehot.astype(vals.dtype), vals,
+                          precision=jax.lax.Precision.HIGHEST)
     return jax.ops.segment_sum(vals, seg, h)
 
 

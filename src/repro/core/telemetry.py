@@ -85,12 +85,8 @@ def _ensure_listener() -> None:
     global _LISTENER_REGISTERED
     if _LISTENER_REGISTERED:
         return
-    try:
-        from jax import monitoring
-        monitoring.register_event_duration_secs_listener(_MONITOR.on_event)
-        _LISTENER_REGISTERED = True
-    except Exception:  # pragma: no cover - monitoring API unavailable
-        pass
+    jax.monitoring.register_event_duration_secs_listener(_MONITOR.on_event)
+    _LISTENER_REGISTERED = True
 
 
 class CompileWatch:

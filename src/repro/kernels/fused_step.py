@@ -11,8 +11,10 @@ over a sequential time grid:
     core modules — the kernel body is jnp, so thermal/renewables/battery
     formulas are single-sourced, never transcribed;
   * the two scalar recurrences (battery SoC, billing-window peak) walk the
-    block in a `fori_loop`, carrying ONLY scalars from tile to tile in the
-    accumulator row — nothing per-step ever returns to HBM;
+    block in a `fori_loop` whose carries are (1, 1) vectors, reading step j
+    of each block row by a lane mask, and pass from tile to tile in the
+    accumulator row — nothing per-step ever returns to HBM; the traced
+    parameters and dequantization constants sit in SMEM;
   * the four exogenous traces (carbon intensity, wet-bulb, price, PV
     capacity factor) arrive QUANTIZED (core/quant.py: bf16 or int8 affine)
     and are dequantized on read inside the kernel, so HBM traffic for the
@@ -32,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import telemetry
 from repro.core.quant import QuantizedTrace, quantize_trace
@@ -39,9 +42,10 @@ from repro.core.quant import QuantizedTrace, quantize_trace
 _LANE = 128
 _BLOCK_T = 256          # time steps per tile (2 lanes-rows of the VPU)
 
-# dense f32[8, S] row indices (f32 tile-aligned: 8 sublanes exactly)
-_R_IT, _R_BT, _R_RISING, _R_PLO, _R_PHI = range(5)
-# traced-parameter lanes of the f32[1, 8] params block
+# dense f32[8, S] row indices (f32 tile-aligned: 8 sublanes exactly);
+# _R_CLOSE flags the steps that close a billing window
+_R_IT, _R_BT, _R_RISING, _R_PLO, _R_PHI, _R_CLOSE = range(6)
+# traced-parameter slots of the f32[1, 8] params block (SMEM)
 _P_CAP, _P_RATE, _P_PVCAP, _P_SETPOINT, _P_SOC0, _P_LAMBDA = range(6)
 # accumulator-row lanes (the kernel's only output, f32[1, 128])
 (_A_SOC, _A_WPEAK, _A_WASC, _A_DEMAND, _A_GRID, _A_GRID_CI, _A_GRID_PR,
@@ -50,13 +54,14 @@ _P_CAP, _P_RATE, _P_PVCAP, _P_SETPOINT, _P_SOC0, _P_LAMBDA = range(6)
 
 
 def _dequant_row(q_ref, meta_ref, k: int):
-    """f32[1, B] reconstruction of quantized-trace row k (dequant-on-read)."""
+    """f32[1, B] reconstruction of quantized-trace row k (dequant-on-read);
+    the scale/zero pair is read from the SMEM meta block."""
     return (q_ref[...].astype(jnp.float32) * meta_ref[0, 2 * k]
             + meta_ref[0, 2 * k + 1])
 
 
 def _kernel(dense_ref, qci_ref, qwb_ref, qpr_ref, qpv_ref, meta_ref,
-            par_ref, acc_ref, *, cfg, n_steps: int, wsteps: int):
+            par_ref, acc_ref, *, cfg, n_steps: int):
     from repro.core import battery as battery_mod
     from repro.core import renewables as renewables_mod
     from repro.core import thermal as thermal_mod
@@ -65,8 +70,7 @@ def _kernel(dense_ref, qci_ref, qwb_ref, qpr_ref, qpv_ref, meta_ref,
     b = _BLOCK_T
     dt = jnp.float32(cfg.dt_h)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
-    t0 = i * b
-    valid = (t0 + lane) < n_steps
+    valid = (i * b + lane) < n_steps
     vf = valid.astype(jnp.float32)
 
     it_kw = dense_ref[_R_IT:_R_IT + 1, :]
@@ -95,7 +99,7 @@ def _kernel(dense_ref, qci_ref, qwb_ref, qpr_ref, qpv_ref, meta_ref,
         pv_kw = renewables_mod.pv_power_kw(par_ref[0, _P_PVCAP], pv_cf)
         net_load, surplus = renewables_mod.net_load_split(load, pv_kw)
     else:
-        pv_kw = surplus = jnp.zeros_like(it_kw)
+        pv_kw = jnp.zeros_like(it_kw)
         net_load = load
 
     if cfg.battery.enabled:
@@ -108,80 +112,88 @@ def _kernel(dense_ref, qci_ref, qwb_ref, qpr_ref, qpv_ref, meta_ref,
             dispatch_lambda=par_ref[0, _P_LAMBDA])
         if cfg.renewables.enabled:
             wc, wd, ccap = battery_mod.surplus_aware_dispatch(wc, wd, surplus)
-        else:
-            ccap = jnp.full_like(it_kw, jnp.inf)
-    else:
-        wc = wd = jnp.zeros_like(it_kw, dtype=bool)
-        ccap = jnp.zeros_like(it_kw)
+        wc = wc.astype(jnp.float32)
+        wd = wd.astype(jnp.float32)
+
+    # The accumulator row is read and written whole: the chip has no
+    # scalar access to VMEM, so one lane is picked or set by a lane mask.
+    acc_lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
 
     @pl.when(i == 0)
     def _init():
-        acc_ref[...] = jnp.zeros((1, _LANE), jnp.float32)
-        acc_ref[0, _A_SOC] = par_ref[0, _P_SOC0]
+        acc_ref[...] = jnp.where(acc_lane == _A_SOC, par_ref[0, _P_SOC0],
+                                 0.0)
 
+    def lane_sum(row):
+        return jnp.sum(row, axis=1, keepdims=True)
+
+    acc = acc_ref[...]
     # block-local sums of the purely elementwise series
-    acc_ref[0, _A_IT] += jnp.sum(it_kw * vf)
-    acc_ref[0, _A_COOL] += jnp.sum(cooling_kw * vf)
-    acc_ref[0, _A_WATER] += jnp.sum(water * vf)
-    acc_ref[0, _A_HEAT] += jnp.sum(heat * vf)
-    acc_ref[0, _A_PV] += jnp.sum(pv_kw * vf)
+    for k, row in ((_A_IT, it_kw), (_A_COOL, cooling_kw), (_A_WATER, water),
+                   (_A_HEAT, heat), (_A_PV, pv_kw)):
+        acc = acc + jnp.where(acc_lane == k, lane_sum(row * vf), 0.0)
 
     # --- the sequential tail: SoC + billing-window recurrences -----------
+    # Each carry is a (1, 1) vector, and step j reads lane j of a block row
+    # by a masked lane sum (exact: every other lane adds zero).
     cap = par_ref[0, _P_CAP]
     rate = par_ref[0, _P_RATE]
     eff = jnp.float32(cfg.battery.round_trip_efficiency)
     dchg = jnp.float32(cfg.pricing.demand_charge_per_kw)
+    close_row = dense_ref[_R_CLOSE:_R_CLOSE + 1, :]
+    zero = jnp.zeros((1, 1), jnp.float32)
 
     def step(j, carry):
         (soc, wpeak, wasc, demand, s_g, s_gci, s_gpr, m_g, s_ck, s_dk,
          s_exp, s_expp, s_cur) = carry
-        t = t0 + j
-        v = t < n_steps
-        net_t = net_load[0, j]
-        ci_t = ci[0, j]
-        pr_t = price[0, j]
+        at = lambda row: lane_sum(jnp.where(lane == j, row, 0.0))
+        mask = at(vf)
+        v = mask > 0.5
+        net_t = at(net_load)
+        ci_t = at(ci)
+        pr_t = at(price)
         if cfg.battery.enabled:
-            wc_t = wc[0, j] & v
+            wc_t = (at(wc) > 0.5) & v
             ck = jnp.minimum(rate, jnp.maximum((cap - soc) / dt, 0.0))
-            ck = jnp.minimum(ck, ccap[0, j])
+            if cfg.renewables.enabled:
+                ck = jnp.minimum(ck, at(ccap))
             ck = jnp.where(wc_t, ck, 0.0)
             dk = jnp.minimum(jnp.minimum(rate, soc / dt), net_t)
-            dk = jnp.where(wd[0, j] & (soc > 0.0) & ~wc_t & v, dk, 0.0)
+            dk = jnp.where((at(wd) > 0.5) & (soc > 0.0) & ~wc_t & v, dk, 0.0)
             soc = jnp.clip(soc + (ck * eff - dk) * dt, 0.0, cap)
             wasc = jnp.where(v, wc_t.astype(jnp.float32), wasc)
         else:
-            ck = dk = jnp.float32(0.0)
+            ck = dk = zero
         if cfg.renewables.enabled:
-            pv_to_batt = jnp.minimum(ck, surplus[0, j])
-            rem = surplus[0, j] - pv_to_batt
-            exp_t = rem if cfg.renewables.export_allowed else jnp.float32(0.0)
-            cur_t = jnp.float32(0.0) if cfg.renewables.export_allowed else rem
+            sur_t = at(surplus)
+            pv_to_batt = jnp.minimum(ck, sur_t)
+            rem = sur_t - pv_to_batt
+            exp_t = rem if cfg.renewables.export_allowed else zero
+            cur_t = zero if cfg.renewables.export_allowed else rem
             grid = net_t + (ck - pv_to_batt) - dk
         else:
-            exp_t = cur_t = jnp.float32(0.0)
+            exp_t = cur_t = zero
             grid = net_t + ck - dk
         grid = jnp.where(v, grid, 0.0)     # flows are >= 0: masking is exact
         if cfg.pricing.enabled:
-            close = (t % wsteps == 0) & (t > 0) & v
+            close = at(close_row) > 0.5
             demand = demand + jnp.where(close, wpeak * dchg, 0.0)
             wpeak = jnp.where(v, jnp.maximum(jnp.where(close, 0.0, wpeak),
                                              grid), wpeak)
-        mask = v.astype(jnp.float32)
         return (soc, wpeak, wasc, demand, s_g + grid, s_gci + grid * ci_t,
                 s_gpr + grid * pr_t * mask, jnp.maximum(m_g, grid),
                 s_ck + ck, s_dk + dk, s_exp + exp_t * mask,
                 s_expp + exp_t * pr_t * mask, s_cur + cur_t * mask)
 
-    carry0 = (acc_ref[0, _A_SOC], acc_ref[0, _A_WPEAK], acc_ref[0, _A_WASC],
-              acc_ref[0, _A_DEMAND], acc_ref[0, _A_GRID],
-              acc_ref[0, _A_GRID_CI], acc_ref[0, _A_GRID_PR],
-              acc_ref[0, _A_GRID_MAX], acc_ref[0, _A_CK], acc_ref[0, _A_DK],
-              acc_ref[0, _A_EXP], acc_ref[0, _A_EXP_PR], acc_ref[0, _A_CUR])
+    carry_lanes = (_A_SOC, _A_WPEAK, _A_WASC, _A_DEMAND, _A_GRID, _A_GRID_CI,
+                   _A_GRID_PR, _A_GRID_MAX, _A_CK, _A_DK, _A_EXP, _A_EXP_PR,
+                   _A_CUR)
+    carry0 = tuple(lane_sum(jnp.where(acc_lane == k, acc, 0.0))
+                   for k in carry_lanes)
     out = jax.lax.fori_loop(0, b, step, carry0)
-    for k, val in zip((_A_SOC, _A_WPEAK, _A_WASC, _A_DEMAND, _A_GRID,
-                       _A_GRID_CI, _A_GRID_PR, _A_GRID_MAX, _A_CK, _A_DK,
-                       _A_EXP, _A_EXP_PR, _A_CUR), out):
-        acc_ref[0, k] = val
+    for k, val in zip(carry_lanes, out):
+        acc = jnp.where(acc_lane == k, val, acc)
+    acc_ref[...] = acc
 
 
 def _quantize(x, store: str) -> QuantizedTrace:
@@ -251,19 +263,23 @@ def fused_facility_totals(it_kw, ci, wet_bulb_c, price, price_lo, price_hi,
         jnp.float32(bcfg.dispatch_lambda) if dispatch_lambda is None
         else dispatch_lambda)
 
-    from repro.core import pricing as pricing_mod
-    wsteps = (pricing_mod.billing_window_steps(cfg.pricing, cfg.dt_h)
-              if cfg.pricing.enabled else 1)
-    kern = functools.partial(_kernel, cfg=cfg, n_steps=s, wsteps=wsteps)
+    if cfg.pricing.enabled:
+        from repro.core import pricing as pricing_mod
+        wsteps = pricing_mod.billing_window_steps(cfg.pricing, cfg.dt_h)
+        t = jnp.arange(s)
+        dense = dense.at[_R_CLOSE, :s].set(
+            ((t % wsteps == 0) & (t > 0)).astype(jnp.float32))
+
+    kern = functools.partial(_kernel, cfg=cfg, n_steps=s)
     trow = lambda: pl.BlockSpec((1, _BLOCK_T), lambda i: (0, i))
-    fixed = lambda n: pl.BlockSpec((1, n), lambda i: (0, 0))
+    smem = lambda: pl.BlockSpec(memory_space=pltpu.SMEM)
     with telemetry.stage_scope("megakernel.facility.pallas"):
         acc = pl.pallas_call(
             kern,
             grid=(n_blocks,),
             in_specs=[pl.BlockSpec((8, _BLOCK_T), lambda i: (0, i)),
-                      trow(), trow(), trow(), trow(), fixed(8), fixed(8)],
-            out_specs=fixed(_LANE),
+                      trow(), trow(), trow(), trow(), smem(), smem()],
+            out_specs=pl.BlockSpec((1, _LANE), lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((1, _LANE), jnp.float32),
             interpret=interpret,
         )(dense, *qrows, meta, params)

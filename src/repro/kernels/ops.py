@@ -2,21 +2,17 @@
 
 Each op dispatches to the Pallas kernel with the pure-jnp oracle available
 in kernels/ref.py for testing.  Interpret mode is resolved PER CALL from the
-active JAX backend (`resolved_interpret`): on CPU the kernel body executes
-as traced jnp for validation; on TPU/GPU the real Mosaic kernel runs.  A
-module-level constant here used to pin interpret=True, which silently ran
-the Python emulation on accelerators — the env override
-`STEAM_PALLAS_INTERPRET=0|1` remains for forcing either mode (e.g. running
-the interpret path on a TPU host while debugging a kernel).
+platform the call runs on (`resolved_interpret`): on CPU the kernel body
+executes as traced jnp for validation; on TPU/GPU the real Mosaic kernel
+runs.  Nothing else selects it.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
 
 from . import first_fit as _first_fit
+from . import fused_step as _fused_step
 from . import power_carbon as _power_carbon
 from . import ssd_chunk as _ssd_chunk
 from repro.core import telemetry
@@ -24,18 +20,21 @@ from repro.core.config import CoolingConfig, PowerModelConfig
 
 
 def resolved_interpret() -> bool:
-    """Should Pallas kernels run in interpret mode for the current backend?
+    """Should Pallas kernels run in interpret mode?
 
-    `STEAM_PALLAS_INTERPRET` (0/1, false/true) wins when set; otherwise
-    interpret mode is exactly "the default backend is CPU".  Resolved at
-    call time, not import time, so late backend selection (jax.config,
-    distributed init) and env changes are honoured.
+    Exactly when the platform the call runs on is CPU: the device pinned by
+    `jax.default_device(...)` if one is, otherwise the default backend.
+    Resolved at call time, not import time, so late backend selection
+    (jax.config, distributed init) is honoured.
     """
-    env = os.environ.get("STEAM_PALLAS_INTERPRET")
-    if env is not None:
-        interp = env.strip().lower() not in ("0", "false", "no", "off", "")
+    pinned = jax.config.jax_default_device
+    if pinned is None:
+        platform = jax.default_backend()
+    elif isinstance(pinned, str):
+        platform = pinned
     else:
-        interp = jax.default_backend() == "cpu"
+        platform = pinned.platform
+    interp = platform == "cpu"
     # observability hook: an active telemetry session records how the call
     # resolved (RunRecord.pallas_interpret); no-op — one attr set — when a
     # session is on, free when off
@@ -105,6 +104,16 @@ def fused_power_carbon(cpu_util, gpu_util, n_gpus, on, ci, dt_h,
         cpu_idle=cpu_cfg.idle_w, cpu_max=cpu_cfg.max_w, cpu_curve=cpu_cfg.model,
         gpu_idle=gpu_cfg.idle_w, gpu_max=gpu_cfg.max_w, gpu_curve=gpu_cfg.model,
         interpret=resolved_interpret())
+
+
+def facility_totals(it_kw, ci, wet_bulb_c, price, price_lo, price_hi, pv_cf,
+                    batt_threshold, ci_rising, cfg, **kwargs):
+    """The megakernel's facility chain as ONE time-blocked kernel
+    (kernels/fused_step.py); returns the run-totals dict."""
+    return _fused_step.fused_facility_totals(
+        it_kw, ci, wet_bulb_c, price, price_lo, price_hi, pv_cf,
+        batt_threshold, ci_rising, cfg, interpret=resolved_interpret(),
+        **kwargs)
 
 
 def first_fit_place(cand_cores, cand_gpus, free_cores, free_gpus):

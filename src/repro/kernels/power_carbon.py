@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128
 _SUBLANE = 8
@@ -49,12 +50,17 @@ def _pad_hosts(x, h: int, hp: int, fill: float = 0.0):
         hp // _LANE, _LANE)
 
 
-def _host_specs(n_scalars: int):
+def _smem():
+    """Whole-array block in scalar memory: the kernels' scalar inputs and
+    outputs live there (a TPU cannot read or write scalars in VMEM)."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _host_specs():
     """(in_specs, power out_spec) shared by both fused kernels: four tiled
-    host vectors plus one (1, n_scalars) scalar block."""
+    host vectors plus one SMEM block of scalars."""
     tile = lambda: pl.BlockSpec((_SUBLANE, _LANE), lambda i: (i, 0))
-    return ([tile(), tile(), tile(), tile(),
-             pl.BlockSpec((1, n_scalars), lambda i: (0, 0))], tile())
+    return [tile(), tile(), tile(), tile(), _smem()], tile()
 
 
 def _kernel(cpu_ref, gpu_ref, ngpu_ref, on_ref, scal_ref,
@@ -154,13 +160,12 @@ def fused_facility_power(cpu_util, gpu_util, n_gpus, on, wet_bulb_c,
         tower_approach=tower_approach, condenser_lift=condenser_lift,
         carnot_eff=carnot_eff, max_cop=max_cop, fan_overhead=fan_overhead,
         evap_l_per_kwh=evap_l_per_kwh)
-    in_specs, power_spec = _host_specs(2)
-    scalar_spec = lambda: pl.BlockSpec((1, 1), lambda i: (0, 0))
+    in_specs, power_spec = _host_specs()
     power, it, cool, water = pl.pallas_call(
         kern,
         grid=(hp // _BLOCK_H,),
         in_specs=in_specs,
-        out_specs=[power_spec, scalar_spec(), scalar_spec(), scalar_spec()],
+        out_specs=[power_spec, _smem(), _smem(), _smem()],
         out_shape=[
             jax.ShapeDtypeStruct((hp // _LANE, _LANE), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
@@ -193,13 +198,12 @@ def fused_power_carbon(cpu_util, gpu_util, n_gpus, on, ci, dt_h, *,
     kern = functools.partial(
         _kernel, cpu_idle=cpu_idle, cpu_max=cpu_max, cpu_curve=cpu_curve,
         gpu_idle=gpu_idle, gpu_max=gpu_max, gpu_curve=gpu_curve)
-    in_specs, power_spec = _host_specs(2)
-    scalar_spec = lambda: pl.BlockSpec((1, 1), lambda i: (0, 0))
+    in_specs, power_spec = _host_specs()
     power, dc, carbon = pl.pallas_call(
         kern,
         grid=(hp // _BLOCK_H,),
         in_specs=in_specs,
-        out_specs=[power_spec, scalar_spec(), scalar_spec()],
+        out_specs=[power_spec, _smem(), _smem()],
         out_shape=[
             jax.ShapeDtypeStruct((hp // _LANE, _LANE), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),

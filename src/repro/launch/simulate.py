@@ -17,6 +17,7 @@ import json
 import numpy as np
 
 from repro.carbontraces.synthetic import make_region_traces
+from repro.compile_cache import enable_compile_cache
 from repro.core import (BatteryConfig, FailureConfig, ShiftingConfig,
                         SimConfig, carbon_reduction_pct, sweep_regions,
                         with_scale)
@@ -42,6 +43,7 @@ def main(argv=None):
     ap.add_argument("--tasks-cap", type=int, default=4096)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tasks, hosts, spec, meta = make_workload(
         args.workload, scale=args.scale, seed=args.seed,

@@ -1,10 +1,12 @@
 """Shared test configuration.
 
-Enables JAX's persistent compilation cache for the suite: the tier-1 tests
-are dominated by XLA compiles of `lax.scan` simulation programs and reduced
-model train steps, so re-runs (local dev loops, CI retries on a warm cache
-volume) skip straight to execution.  The cache key includes the HLO and
-compile options, so it is safe across code changes — edits simply miss.
+Enables JAX's persistent compilation cache for the suite (repro.compile_cache:
+`JAX_COMPILATION_CACHE_DIR` if set, else `<checkout>/.jax_cache`): the
+tier-1 tests are dominated by XLA compiles of `lax.scan` simulation programs
+and reduced model train steps, so re-runs (local dev loops, CI retries on a
+warm cache volume) skip straight to execution.  The cache key includes the
+HLO and compile options, so it is safe across code changes — edits simply
+miss.
 """
 from __future__ import annotations
 
@@ -12,21 +14,14 @@ import os
 
 import jax
 
-_CACHE_DIR = os.environ.get(
-    "STEAMX_JAX_CACHE",
-    os.path.join(os.path.dirname(__file__), "..", ".jax_cache"))
+from repro.compile_cache import enable_compile_cache
 
-try:
-    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
-    # default only caches >1s compiles; tier-1 has many ~0.5s scan programs
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-except Exception:  # pragma: no cover - older jax without these flags
-    pass
-
-# subprocess-based tests (test_elastic, test_distributed) spawn fresh python
-# interpreters that never import this conftest; the env vars hand them the
-# same cache
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", _CACHE_DIR)
+enable_compile_cache()
+# default only caches >1s compiles; tier-1 has many ~0.5s scan programs.
+# Subprocess-based tests (test_elastic, test_distributed) spawn fresh python
+# interpreters that never import this conftest; the env var hands them the
+# same threshold (enable_compile_cache hands them the directory)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.1")
 
 

@@ -455,9 +455,10 @@ class TestShardMapExecutor:
             grid.shard_map_callable(tasks, hosts, SimConfig(n_steps=N_STEPS))
 
     def test_multidevice_weak_scaling(self):
-        """4 forced host devices: divisibility enforced, results bitwise
-        equal to the single-program path, record carries the mesh/chunk
-        plan.  Subprocess: device count is fixed at backend init."""
+        """4 forced host devices: the cells land on all four, divisibility
+        is enforced, and results are bitwise equal to the single-program
+        path except the carbon sums, which agree to one ulp.  Subprocess:
+        device count is fixed at backend init."""
         import os
         import subprocess
         import sys
@@ -484,9 +485,16 @@ cfg = SimConfig(n_steps=S, battery=BatteryConfig(enabled=True),
 axes = [trace_axis(traces)]
 full = sweep_grid(tasks, hosts, cfg, axes)
 weak = sweep_grid(tasks, hosts, cfg, axes, executor="shard_map")
+assert len(weak.total_carbon_kg.sharding.device_set) == 4
 for f in full._fields:
     a = getattr(full, f)
     if a is None:
+        continue
+    if f in ("op_carbon_kg", "total_carbon_kg"):
+        # XLA:CPU rounds the partitioned program's S-step carbon sum
+        # differently in the last place: one ulp, not bitwise
+        np.testing.assert_array_max_ulp(np.asarray(a),
+                                        np.asarray(getattr(weak, f)), 1)
         continue
     assert np.array_equal(np.asarray(a), np.asarray(getattr(weak, f))), f
 try:  # 6 cells over 4 devices: must refuse, not pad silently

@@ -275,19 +275,23 @@ def test_fused_kernel_quantized_stores(store, rel):
 # satellite: interpret-mode dispatch (kernels/ops.resolved_interpret)
 # ---------------------------------------------------------------------------
 
-def test_resolved_interpret_follows_backend(monkeypatch):
-    monkeypatch.delenv("STEAM_PALLAS_INTERPRET", raising=False)
-    assert resolved_interpret() == (jax.default_backend() == "cpu")
+def test_resolved_interpret_follows_backend():
+    assert resolved_interpret() is (jax.default_backend() == "cpu")
 
 
-@pytest.mark.parametrize("env,want", [
-    ("1", True), ("true", True), ("yes", True),
-    ("0", False), ("false", False), ("no", False), ("off", False),
-    ("", False),
-])
-def test_resolved_interpret_env_override(monkeypatch, env, want):
-    monkeypatch.setenv("STEAM_PALLAS_INTERPRET", env)
-    assert resolved_interpret() is want
+@pytest.mark.parametrize("pin", [None, "cpu", "cpu-device"])
+@pytest.mark.parametrize("backend", ["cpu", "tpu", "gpu"])
+def test_resolved_interpret_follows_backend_alone(monkeypatch, backend, pin):
+    """Interpret mode is exactly "the platform the call runs on is CPU":
+    the default backend, unless `jax.default_device` pins a device (as a
+    run that checks the chip against the host CPU does)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if pin is None:
+        assert resolved_interpret() is (backend == "cpu")
+        return
+    device = "cpu" if pin == "cpu" else jax.devices("cpu")[0]
+    with jax.default_device(device):
+        assert resolved_interpret() is True
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,111 @@
+"""The main-path Pallas kernels compile for a TPU v5e, at deployment widths.
+
+Every other kernel test runs the Pallas interpreter on the CPU, which
+accepts code the chip's compiler refuses (scalar stores to VMEM, dynamic
+lane reads, unaligned blocks).  Here the TPU compiler installed with JAX
+compiles each kernel for a described `v5e:2x2` topology, with no chip
+attached: the widths are the SURF-calibrated datacenter (277 hosts, 124 days
+= 11,904 steps at 15 min), the Borg one (1,534 hosts: two host tiles) and
+an 8-region fleet.  Nothing runs, so results are checked elsewhere.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and test workers import every file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import (BatteryConfig, CoolingConfig, PricingConfig,
+                        RenewableConfig, SimConfig)
+from repro.core.config import PowerModelConfig
+from repro.kernels import ops
+
+SURF_HOSTS = 277
+BORG_HOSTS = 1534
+SURF_STEPS = 11904          # 124 days at dt = 0.25 h
+REGIONS = 8
+
+CPU = PowerModelConfig(80.0, 250.0, "sqrt")
+GPU = PowerModelConfig(40.0, 300.0, "linear")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A v5e device to compile for, with the persistent cache off: an entry
+    compiled for a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compiled(monkeypatch):
+    """Compile `fn` for the chip through the ops layer, kernels compiled
+    (the ops layer would pick the interpreter from this CPU backend)."""
+    monkeypatch.setattr(ops, "resolved_interpret", lambda: False)
+
+    def compile_(fn, *shapes):
+        exe = jax.jit(fn).lower(*shapes).compile()
+        assert "tpu_custom_call" in exe.as_text()
+        return exe
+    return compile_
+
+
+def _f32(sharding, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+@pytest.mark.parametrize("h", [SURF_HOSTS, BORG_HOSTS])
+def test_power_carbon_compiles(one_chip, compiled, h):
+    compiled(lambda cu, gu, ng, on, ci, dt: ops.fused_power_carbon(
+        cu, gu, ng, on, ci, dt, CPU, GPU),
+        *[_f32(one_chip, h)] * 4, _f32(one_chip), _f32(one_chip))
+
+
+@pytest.mark.parametrize("h", [SURF_HOSTS, BORG_HOSTS])
+def test_facility_power_compiles(one_chip, compiled, h):
+    compiled(lambda cu, gu, ng, on, wb, sp: ops.facility_power(
+        cu, gu, ng, on, wb, sp, CPU, GPU, CoolingConfig(enabled=True)),
+        *[_f32(one_chip, h)] * 4, _f32(one_chip), _f32(one_chip))
+
+
+def test_facility_power_batched_compiles(one_chip, compiled):
+    compiled(lambda cu, gu, ng, on, wb, sp: ops.facility_power_batched(
+        cu, gu, ng, on, wb, sp, CPU, GPU, CoolingConfig(enabled=True)),
+        *[_f32(one_chip, REGIONS, SURF_HOSTS)] * 4,
+        _f32(one_chip, REGIONS), _f32(one_chip, REGIONS))
+
+
+@pytest.mark.parametrize("store", ["bf16", "int8"])
+def test_facility_totals_compiles(one_chip, compiled, store):
+    """The megakernel's facility chain with every technique composed."""
+    cfg = SimConfig(
+        n_steps=SURF_STEPS,
+        cooling=CoolingConfig(enabled=True, heat_reuse_fraction=0.3),
+        pricing=PricingConfig(enabled=True, billing_window_h=24.0),
+        renewables=RenewableConfig(enabled=True, pv_capacity_kw=40.0),
+        battery=BatteryConfig(enabled=True, capacity_kwh=100.0))
+    series = [_f32(one_chip, SURF_STEPS)] * 8
+    rising = jax.ShapeDtypeStruct((SURF_STEPS,), jnp.bool_, sharding=one_chip)
+    compiled(lambda *xs: ops.facility_totals(*xs, cfg, trace_store=store),
+             *series, rising)

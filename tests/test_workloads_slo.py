@@ -10,6 +10,7 @@ arrival-rate family, workload class mixes, and the `arrival_trace` /
 import functools
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -520,9 +521,9 @@ def _collect_scans(jaxpr, out):
         for v in eqn.params.values():
             vs = v if isinstance(v, (list, tuple)) else (v,)
             for x in vs:
-                if isinstance(x, jax.core.ClosedJaxpr):
+                if isinstance(x, jax.extend.core.ClosedJaxpr):
                     _collect_scans(x.jaxpr, out)
-                elif isinstance(x, jax.core.Jaxpr):
+                elif isinstance(x, jax.extend.core.Jaxpr):
                     _collect_scans(x, out)
     return out
 
