@@ -314,13 +314,14 @@ def stage_scheduler(cfg: SimConfig) -> Stage:
              & ~shift_ok).astype(jnp.float32))
         order = (resilience_mod.host_rank(state.hosts, state.t)
                  if reactive else None)
-        tasks = scheduler_mod.schedule_step(state.tasks, state.hosts, state.t,
-                                            shift_ok, cfg.scheduler,
-                                            slots=ctx.get("slots_per_step"),
-                                            host_order=order,
-                                            presorted=presorted)
-        metrics = state.metrics._replace(
-            n_shift_delays=state.metrics.n_shift_delays + n_delayed)
+        tasks, iters, placed = scheduler_mod.schedule_step(
+            state.tasks, state.hosts, state.t, shift_ok, cfg.scheduler,
+            slots=ctx.get("slots_per_step"), host_order=order,
+            presorted=presorted)
+        m = state.metrics
+        metrics = m._replace(n_shift_delays=m.n_shift_delays + n_delayed,
+                             first_fit_iters=m.first_fit_iters + iters,
+                             first_fit_placed=m.first_fit_placed + placed)
         return state._replace(tasks=tasks, metrics=metrics), ctx
     return fn
 
@@ -798,19 +799,21 @@ def _build_demand_step(cfg: SimConfig, dyn: dict):
         for stage in stages:
             with telemetry_mod.stage_scope(_stage_label(stage)):
                 state, ctx = stage(state, ctx)
-        cpu_u, gpu_u = scheduler_mod.host_utilization(state.tasks, state.hosts)
-        if resil:
-            cpu_u = cpu_u * state.throttle
-            gpu_u = gpu_u * state.throttle
-        on = (state.hosts.active & state.hosts.up).astype(jnp.float32)
-        if cfg.use_pallas:
-            from repro.kernels import ops as pc_ops
-            p = pc_ops.host_power(cpu_u, gpu_u, state.hosts.n_gpus, on,
+        with telemetry_mod.stage_scope("stage_it_power"):
+            cpu_u, gpu_u = scheduler_mod.host_utilization(state.tasks,
+                                                          state.hosts)
+            if resil:
+                cpu_u = cpu_u * state.throttle
+                gpu_u = gpu_u * state.throttle
+            on = (state.hosts.active & state.hosts.up).astype(jnp.float32)
+            if cfg.use_pallas:
+                from repro.kernels import ops as pc_ops
+                p = pc_ops.host_power(cpu_u, gpu_u, state.hosts.n_gpus, on,
+                                      cfg.cpu_power, cfg.gpu_power)
+            else:
+                p = host_power_kw(cpu_u, gpu_u, state.hosts.n_gpus, on,
                                   cfg.cpu_power, cfg.gpu_power)
-        else:
-            p = host_power_kw(cpu_u, gpu_u, state.hosts.n_gpus, on,
-                              cfg.cpu_power, cfg.gpu_power)
-        it_kw = jnp.sum(p)
+            it_kw = jnp.sum(p)
         # throttle the step RAN under (the probe-bus channel; the recurrence
         # below replaces state.throttle with the NEXT step's value)
         applied_throttle = state.throttle if resil else jnp.float32(1.0)
@@ -981,11 +984,12 @@ def _simulate_megakernel(state0: SimState, inputs: StepInputs,
     if (cfg.use_pallas and not cfg.collect_series and not cfg.probes.enabled
             and not cfg.resilience.enabled):
         from repro.kernels import ops as pc_ops
-        totals = pc_ops.facility_totals(
-            it_series, inputs.ci, inputs.wet_bulb_c, inputs.price,
-            inputs.price_lo, inputs.price_hi, inputs.pv_cf,
-            inputs.batt_threshold, inputs.ci_rising, cfg,
-            trace_store=cfg.trace_store, **chain_kwargs)
+        with telemetry_mod.stage_scope("megakernel.facility"):
+            totals = pc_ops.facility_totals(
+                it_series, inputs.ci, inputs.wet_bulb_c, inputs.price,
+                inputs.price_lo, inputs.price_hi, inputs.pv_cf,
+                inputs.batt_threshold, inputs.ci_rising, cfg,
+                trace_store=cfg.trace_store, **chain_kwargs)
         final = _merge_facility_totals(final, totals, cfg, dyn)
         return final, None
     with telemetry_mod.stage_scope("megakernel.facility"):

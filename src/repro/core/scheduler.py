@@ -20,6 +20,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import telemetry as telemetry_mod
 from .config import SchedulerConfig
 from .state import HostTable, TaskTable, PENDING, RUNNING
 
@@ -142,7 +143,9 @@ def _first_k_by_priority_reference(mask, priority, k: int, levels: int):
 def schedule_first_fit(tasks: TaskTable, hosts: HostTable, now, shift_ok,
                        cfg: SchedulerConfig, slots=None, host_order=None,
                        presorted: bool = False):
-    """Exact bounded first-fit.  Returns updated task table.
+    """Exact bounded first-fit.  Returns `(tasks, iters, placed)`: the
+    updated task table, the placement loop's iterations and the slots it
+    placed (f32 scalars, the loop's work and its useful part).
 
     `cfg.slots_per_step` is the STATIC placement bound (it shapes the
     compiled loop).  `slots`, when given, is a TRACED per-run slot count
@@ -164,45 +167,58 @@ def schedule_first_fit(tasks: TaskTable, hosts: HostTable, now, shift_ok,
     per-step `[L*T]` level-major flatten+cumsum disappears entirely.  The
     engine permutes the table once per simulation and sets this; direct
     callers with arrival-ordered tables keep the default.
+
+    Under a telemetry session the parts are named for device traces:
+    `stage_scheduler.candidates` (eligibility, cumsum, searchsorted and the
+    per-slot needs), `stage_scheduler.free_capacity` (per-host sums),
+    `stage_scheduler.first_fit` (the placement loop) and
+    `stage_scheduler.commit` (the deferred `[T]` table writes).
     """
     k = cfg.slots_per_step
     t = tasks.arrival.shape[0]
     h_n = hosts.cores.shape[0]
-    elig = _eligible(tasks, now, shift_ok)
-    multi = cfg.priority_levels > 1 and not presorted
-    if multi:
-        # level-major flattened mask: merged (priority desc, arrival) order
-        prio = jnp.asarray(tasks.priority)
-        lvl = jnp.arange(cfg.priority_levels - 1, -1, -1, dtype=prio.dtype)
-        m = (elig[None, :] & (prio[None, :] == lvl[:, None])).reshape(-1)
-    else:  # single class, or presorted rows: row order IS admission order
-        m = elig
-    # One cumsum serves BOTH directions of the candidate mapping:
-    # slot -> row (cand, via k binary searches) and row -> slot (rank,
-    # via a gather) — the k-th set bit of m sits at the first position
-    # whose cumsum equals k+1, and a set row's rank is its cumsum - 1.
-    csum = jnp.cumsum(m.astype(jnp.int32))
-    wanted = jnp.arange(1, k + 1, dtype=jnp.int32)
-    idx = jnp.searchsorted(csum, wanted, side="left").astype(jnp.int32)
-    cand = jnp.where(wanted <= csum[-1], idx % t if multi else idx, -1)
-    free_c, free_g = free_capacity(tasks, hosts)
+    scope = telemetry_mod.stage_scope
+    with scope("stage_scheduler.candidates"):
+        elig = _eligible(tasks, now, shift_ok)
+        multi = cfg.priority_levels > 1 and not presorted
+        if multi:
+            # level-major flattened mask: merged (priority desc, arrival)
+            prio = jnp.asarray(tasks.priority)
+            lvl = jnp.arange(cfg.priority_levels - 1, -1, -1,
+                             dtype=prio.dtype)
+            m = (elig[None, :] & (prio[None, :] == lvl[:, None])).reshape(-1)
+        else:  # single class, or presorted rows: row order IS admission order
+            m = elig
+        # One cumsum serves BOTH directions of the candidate mapping:
+        # slot -> row (cand, via k binary searches) and row -> slot (rank,
+        # via a gather) — the k-th set bit of m sits at the first position
+        # whose cumsum equals k+1, and a set row's rank is its cumsum - 1.
+        csum = jnp.cumsum(m.astype(jnp.int32))
+        wanted = jnp.arange(1, k + 1, dtype=jnp.int32)
+        idx = jnp.searchsorted(csum, wanted, side="left").astype(jnp.int32)
+        cand = jnp.where(wanted <= csum[-1], idx % t if multi else idx, -1)
+    with scope("stage_scheduler.free_capacity"):
+        free_c, free_g = free_capacity(tasks, hosts)
     usable = hosts.active & hosts.up
     hidx = jnp.arange(h_n, dtype=jnp.int32)
-    # per-slot resource needs, gathered ONCE before the loop (the body used
-    # to re-gather from the [T] columns every iteration, a batched gather
-    # per iteration under vmapped grids)
-    cj = jnp.maximum(cand, 0)
-    nc_all = jnp.where(cand >= 0, tasks.cores[cj], 0.0)
-    ng_all = jnp.where(cand >= 0, tasks.gpus[cj], 0.0)
-    # suffix minima of the per-slot needs: once NO remaining candidate fits
-    # on ANY usable host, every later iteration is a placement no-op (it
-    # skips the candidate and changes no capacity), so the loop may stop —
-    # bit-for-bit the same outcome.  Saturated steps (full hosts behind a
-    # backlog, e.g. shifting holding a green-window burst) used to burn all
-    # k iterations doing nothing.
-    inf32 = jnp.float32(jnp.inf)
-    suf_c = jax.lax.cummin(jnp.where(cand >= 0, nc_all, inf32)[::-1])[::-1]
-    suf_g = jax.lax.cummin(jnp.where(cand >= 0, ng_all, inf32)[::-1])[::-1]
+    with scope("stage_scheduler.candidates"):
+        # per-slot resource needs, gathered ONCE before the loop (the body
+        # used to re-gather from the [T] columns every iteration, a batched
+        # gather per iteration under vmapped grids)
+        cj = jnp.maximum(cand, 0)
+        nc_all = jnp.where(cand >= 0, tasks.cores[cj], 0.0)
+        ng_all = jnp.where(cand >= 0, tasks.gpus[cj], 0.0)
+        # suffix minima of the per-slot needs: once NO remaining candidate
+        # fits on ANY usable host, every later iteration is a placement
+        # no-op (it skips the candidate and changes no capacity), so the
+        # loop may stop — bit-for-bit the same outcome.  Saturated steps
+        # (full hosts behind a backlog, e.g. shifting holding a green-window
+        # burst) used to burn all k iterations doing nothing.
+        inf32 = jnp.float32(jnp.inf)
+        suf_c = jax.lax.cummin(
+            jnp.where(cand >= 0, nc_all, inf32)[::-1])[::-1]
+        suf_g = jax.lax.cummin(
+            jnp.where(cand >= 0, ng_all, inf32)[::-1])[::-1]
 
     # Sequential first-fit over the candidate slots, restructured for the
     # batched (vmapped-grid) hot path:
@@ -252,37 +268,43 @@ def schedule_first_fit(tasks: TaskTable, hosts: HostTable, now, shift_ok,
             jnp.where(placed, h.astype(jnp.int32), -1))
         return i + 1, free_c, free_g, sel_host
 
-    _, free_c, free_g, sel_host = jax.lax.while_loop(
-        cond, body,
-        (jnp.int32(0), free_c, free_g, jnp.full((k,), -1, jnp.int32)))
-    # Deferred table writes via the INVERSE candidate map: each row's slot
-    # is its rank in the admission order (csum - 1), so a [T] gather from
-    # sel_host replaces the three [T]-target scatters this used to do —
-    # XLA CPU serializes batched scatters per lane, and they were ~half
-    # the scheduler stage's cost under vmapped grids.  Rows map to at most
-    # one slot and vice versa, so the select-form updates are bitwise the
-    # scatters they replace.
-    if multi:
-        # clip is index safety only: an out-of-range priority has
-        # m[pos_t] == False (it matched no level), so it never places —
-        # exactly the original per-level behaviour
-        lvl_t = (cfg.priority_levels - 1
-                 - jnp.clip(prio, 0, cfg.priority_levels - 1))
-        pos_t = lvl_t.astype(jnp.int32) * t + jnp.arange(t, dtype=jnp.int32)
-        rank = csum[pos_t] - 1
-        in_k = m[pos_t] & (rank < k)
-    else:
-        rank = csum - 1
-        in_k = elig & (rank < k)
-    host_t = sel_host[jnp.clip(rank, 0, k - 1)]
-    placed_t = in_k & (host_t >= 0)
-    status = jnp.where(placed_t, RUNNING, tasks.status).astype(
-        tasks.status.dtype)
-    host = jnp.where(placed_t, jnp.maximum(host_t, 0),
-                     tasks.host).astype(tasks.host.dtype)
-    first_start = jnp.where(placed_t, jnp.minimum(tasks.first_start, now),
-                            tasks.first_start)
-    return tasks._replace(status=status, host=host, first_start=first_start)
+    with scope("stage_scheduler.first_fit"):
+        iters, free_c, free_g, sel_host = jax.lax.while_loop(
+            cond, body,
+            (jnp.int32(0), free_c, free_g, jnp.full((k,), -1, jnp.int32)))
+    with scope("stage_scheduler.commit"):
+        # Deferred table writes via the INVERSE candidate map: each row's
+        # slot is its rank in the admission order (csum - 1), so a [T]
+        # gather from sel_host replaces the three [T]-target scatters this
+        # used to do — XLA CPU serializes batched scatters per lane, and
+        # they were ~half the scheduler stage's cost under vmapped grids.
+        # Rows map to at most one slot and vice versa, so the select-form
+        # updates are bitwise the scatters they replace.
+        if multi:
+            # clip is index safety only: an out-of-range priority has
+            # m[pos_t] == False (it matched no level), so it never places —
+            # exactly the original per-level behaviour
+            lvl_t = (cfg.priority_levels - 1
+                     - jnp.clip(prio, 0, cfg.priority_levels - 1))
+            pos_t = (lvl_t.astype(jnp.int32) * t
+                     + jnp.arange(t, dtype=jnp.int32))
+            rank = csum[pos_t] - 1
+            in_k = m[pos_t] & (rank < k)
+        else:
+            rank = csum - 1
+            in_k = elig & (rank < k)
+        host_t = sel_host[jnp.clip(rank, 0, k - 1)]
+        placed_t = in_k & (host_t >= 0)
+        status = jnp.where(placed_t, RUNNING, tasks.status).astype(
+            tasks.status.dtype)
+        host = jnp.where(placed_t, jnp.maximum(host_t, 0),
+                         tasks.host).astype(tasks.host.dtype)
+        first_start = jnp.where(placed_t,
+                                jnp.minimum(tasks.first_start, now),
+                                tasks.first_start)
+        n_placed = jnp.sum((sel_host >= 0).astype(jnp.float32))
+    return (tasks._replace(status=status, host=host, first_start=first_start),
+            iters.astype(jnp.float32), n_placed)
 
 
 def schedule_aggregate(tasks: TaskTable, hosts: HostTable, now, shift_ok,
@@ -327,6 +349,9 @@ def schedule_aggregate(tasks: TaskTable, hosts: HostTable, now, shift_ok,
 def schedule_step(tasks: TaskTable, hosts: HostTable, now, shift_ok,
                   cfg: SchedulerConfig, slots=None, host_order=None,
                   presorted: bool = False):
+    """One step of the configured scheduler: `(tasks, iters, placed)` as
+    `schedule_first_fit` returns them; the aggregate mode runs no placement
+    loop and counts 0 for both."""
     if cfg.mode == "first_fit":
         return schedule_first_fit(tasks, hosts, now, shift_ok, cfg,
                                   slots=slots, host_order=host_order,
@@ -337,5 +362,6 @@ def schedule_step(tasks: TaskTable, hosts: HostTable, now, shift_ok,
                 "scheduler mode 'aggregate' admits the longest FIFO prefix "
                 "and cannot honor priority classes; use mode='first_fit' "
                 "with priority_levels > 1")
-        return schedule_aggregate(tasks, hosts, now, shift_ok, cfg)
+        zero = jnp.float32(0.0)
+        return schedule_aggregate(tasks, hosts, now, shift_ok, cfg), zero, zero
     raise ValueError(f"unknown scheduler mode '{cfg.mode}'")

@@ -122,6 +122,8 @@ class MetricsAcc(NamedTuple):
     throttled_h: jax.Array     # f32[] hours spent thermally throttled
     derate_h: jax.Array        # f32[] hours with chiller/PDU derated
     n_spills: jax.Array        # f32[] tasks spilled to another region (fleet)
+    first_fit_iters: jax.Array   # f32[] first-fit placement-loop iterations
+    first_fit_placed: jax.Array  # f32[] ... of which placed a task
 
 
 class SimState(NamedTuple):
@@ -348,7 +350,8 @@ def init_metrics() -> MetricsAcc:
                       n_shift_delays=z, energy_cost=z, demand_cost=z,
                       window_peak_kw=z, pv_energy=z, export_energy=z,
                       curtailed_energy=z, export_revenue=z, heat_reuse=z,
-                      n_stops=z, throttled_h=z, derate_h=z, n_spills=z)
+                      n_stops=z, throttled_h=z, derate_h=z, n_spills=z,
+                      first_fit_iters=z, first_fit_placed=z)
 
 
 def init_sim_state(tasks: TaskTable, hosts: HostTable, seed: int = 0) -> SimState:

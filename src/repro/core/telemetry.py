@@ -5,25 +5,30 @@ Everything here is **zero-overhead when disabled** (the default):
 * :func:`span` / :func:`stage_scope` return ``nullcontext`` unless a
   :class:`Telemetry` session is active, so the engine's numerics are
   bitwise-identical with telemetry on or off — spans only measure host
-  time and annotate device traces, they never touch values.
+  time and name operations, they never touch values.
 * The per-step probe bus is opt-in via ``SimConfig.probes`` and lives in
   its own preallocated ring buffer threaded through the scan carry; with
   ``ProbeConfig.enabled = False`` the buffer is the ``None`` leafless
   pytree node and the step function is unchanged.
 
-Host-side spans are exported as Chrome-trace JSON (loadable in Perfetto
-or ``chrome://tracing``); device-side stage boundaries come from
-``jax.profiler.TraceAnnotation`` + ``jax.named_scope`` wrappers that
-:func:`stage_scope` installs around every engine stage and the
-megakernel halves.
+Host-side spans (:func:`span`) are exported as Chrome-trace JSON (loadable
+in Perfetto or ``chrome://tracing``) and, for their duration, open a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+shows them on the host plane, on the device's clock.  Device work is named
+by :func:`stage_scope`, a ``jax.named_scope`` around every engine stage,
+the megakernel halves and the scheduler's parts: the names become the
+``op_name`` metadata of the compiled module's instructions, which is how a
+device trace's operations are put down to a stage.
 
 Compile activity is observed through ``jax.monitoring``'s
-``/jax/core/compile/backend_compile_duration`` event stream: one event
-fires per backend compile (including persistent-cache deserialisation;
-in-memory jit cache hits fire none), which powers both the
-compile-vs-steady-state split in :class:`RunRecord` and the
+``/jax/core/compile/*`` duration events: ``backend_compile_duration``
+fires once per backend compile (including persistent-cache
+deserialisation; in-memory jit cache hits fire none), which powers both
+the compile-vs-steady-state split in :class:`RunRecord` and the
 :func:`recompile_guard` detector that turns "this sweep recompiles per
-cell" from a perf mystery into a test failure.
+cell" from a perf mystery into a test failure; ``jaxpr_trace_duration``
+and ``jaxpr_to_mlir_module_duration`` time the tracing and lowering that
+precede it (:class:`CompileWatch`).
 
 Activate for a whole process with ``STEAM_TELEMETRY=1`` (output under
 ``STEAM_TELEMETRY_DIR``, default ``results/telemetry``), or locally::
@@ -51,6 +56,8 @@ import jax
 import jax.numpy as jnp
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 
 class RecompileError(RuntimeError):
@@ -61,20 +68,52 @@ class RecompileError(RuntimeError):
 # Compile-event monitor (module-level; one listener for the whole process)
 # ---------------------------------------------------------------------------
 
+class _WallClock:
+    """Seconds covered by intervals that are reported as they end (a
+    jax.monitoring duration event fires at the end of its work).  An
+    interval inside a later one counts once: an inner jit traced while an
+    outer one traces fires its own event, whose time the outer one's
+    already holds."""
+
+    _KEEP = 64  # later intervals start after all but the last few ended
+
+    def __init__(self) -> None:
+        self.seconds = 0.0
+        self._spans: list = []  # disjoint (start, end), ascending
+
+    def add(self, duration: float) -> None:
+        end = time.perf_counter()
+        start = lo = end - duration
+        covered = 0.0
+        while self._spans and self._spans[-1][1] > start:
+            s, e = self._spans.pop()
+            covered += e - max(s, start)
+            lo = min(lo, s)
+        self.seconds += duration - covered
+        self._spans.append((lo, end))
+        del self._spans[:-self._KEEP]
+
+
 class _CompileMonitor:
-    """Accumulates backend-compile count and seconds from jax.monitoring."""
+    """Accumulates backend-compile count and seconds, and the wall seconds
+    spent tracing to a jaxpr and lowering to MLIR, from jax.monitoring."""
 
     def __init__(self) -> None:
         self.count = 0
         self.seconds = 0.0
+        self.tracing = _WallClock()
+        self.lowering = _WallClock()
         self._lock = threading.Lock()
 
     def on_event(self, event: str, duration: float, **kwargs: Any) -> None:
-        if event != _COMPILE_EVENT:
-            return
         with self._lock:
-            self.count += 1
-            self.seconds += float(duration)
+            if event == _COMPILE_EVENT:
+                self.count += 1
+                self.seconds += float(duration)
+            elif event == _TRACE_EVENT:
+                self.tracing.add(float(duration))
+            elif event == _LOWER_EVENT:
+                self.lowering.add(float(duration))
 
 
 _MONITOR = _CompileMonitor()
@@ -90,11 +129,20 @@ def _ensure_listener() -> None:
 
 
 class CompileWatch:
-    """Delta view over the compile monitor; see :func:`compile_watch`."""
+    """Delta view over the compile monitor; see :func:`compile_watch`.
 
-    def __init__(self) -> None:
-        self._count0 = _MONITOR.count
-        self._seconds0 = _MONITOR.seconds
+    ``count`` and ``seconds`` are backend compiles; ``trace_seconds`` and
+    ``lower_seconds`` the wall time of the jaxpr tracing and MLIR lowering
+    before them (a jit traced inside another counts once).
+    ``since_start=True`` reads the process's totals since compile activity
+    was first watched (the first :func:`compile_watch` or :func:`enable`)
+    instead of counting from the watch's creation."""
+
+    def __init__(self, since_start: bool = False) -> None:
+        self._count0 = 0 if since_start else _MONITOR.count
+        self._seconds0 = 0.0 if since_start else _MONITOR.seconds
+        self._trace0 = 0.0 if since_start else _MONITOR.tracing.seconds
+        self._lower0 = 0.0 if since_start else _MONITOR.lowering.seconds
 
     @property
     def count(self) -> int:
@@ -104,10 +152,19 @@ class CompileWatch:
     def seconds(self) -> float:
         return _MONITOR.seconds - self._seconds0
 
+    @property
+    def trace_seconds(self) -> float:
+        return _MONITOR.tracing.seconds - self._trace0
+
+    @property
+    def lower_seconds(self) -> float:
+        return _MONITOR.lowering.seconds - self._lower0
+
 
 @contextlib.contextmanager
 def compile_watch():
-    """Count backend compiles (and their seconds) inside the block.
+    """Count backend compiles (and their seconds), and the seconds spent
+    tracing and lowering, inside the block.
 
     Works standalone — no active telemetry session required — so the
     benchmarks can split compile time from steady-state throughput
@@ -262,10 +319,13 @@ class Telemetry:
 
     @contextlib.contextmanager
     def span(self, name: str, **args: Any):
-        """Host-side timed span, recorded as a Chrome-trace "X" event."""
+        """Host-side timed span, recorded as a Chrome-trace "X" event and,
+        for its duration, as a profiler ``TraceAnnotation`` of the same
+        name (a no-op unless a profiler trace is being captured)."""
         ts = self._now_us()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name):
+                yield
         finally:
             dur = self._now_us() - ts
             ev = {"name": name, "ph": "X", "ts": ts, "dur": dur,
@@ -357,23 +417,19 @@ def span(name: str, **args: Any):
 
 
 def stage_scope(name: str):
-    """Trace-time annotation for an engine stage / kernel half.
+    """Name the operations traced inside the block after an engine stage,
+    a kernel half or a part of one.
 
-    Combines ``jax.named_scope`` (names ops in lowered HLO) with
-    ``jax.profiler.TraceAnnotation`` (stage boundaries in device
-    profiles).  Returns ``nullcontext`` when disabled, so tracing —
-    and therefore the compiled computation — is untouched by default.
+    A ``jax.named_scope``: it acts while Python traces the step, and its
+    name becomes part of the ``op_name`` metadata of every instruction the
+    block lowers to, which is what maps a device trace's operations to
+    stages.  It records no event of its own, at trace time or at run time.
+    Returns ``nullcontext`` when disabled, so tracing — and therefore the
+    compiled computation — is untouched by default.
     """
-    tel = _ACTIVE
-    if tel is None:
+    if _ACTIVE is None:
         return contextlib.nullcontext()
-    stack = contextlib.ExitStack()
-    stack.enter_context(jax.named_scope(name))
-    try:
-        stack.enter_context(jax.profiler.TraceAnnotation(name))
-    except Exception:  # pragma: no cover - annotation outside profiler ok
-        pass
-    return stack
+    return jax.named_scope(name)
 
 
 def note_pallas_interpret(interpret: bool) -> None:

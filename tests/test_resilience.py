@@ -35,7 +35,8 @@ from repro.core import (CoolingConfig, FailureConfig, FleetSpec,
                         make_task_table, next_throttle, simulate,
                         simulate_fleet, summarize)
 from repro.core import resilience as resilience_mod
-from repro.core.scheduler import schedule_aggregate, schedule_first_fit
+from repro.core.scheduler import (schedule_aggregate, schedule_first_fit,
+                                  schedule_step)
 from repro.core.shifting import forward_window_quantiles
 from repro.core.state import (INVALID, PENDING, init_metrics, pad_task_table)
 
@@ -75,8 +76,9 @@ def test_first_fit_skips_unusable_hosts_zero_footprint(flag):
     down-host mask is the only thing keeping the task off dead hardware."""
     hosts = make_host_table(2, 2)._replace(
         **{flag: jnp.asarray([False, True])})
-    out = schedule_first_fit(_zero_footprint_task(), hosts, jnp.float32(0.0),
-                             jnp.ones(1, bool), SchedulerConfig())
+    out, _, _ = schedule_first_fit(_zero_footprint_task(), hosts,
+                                   jnp.float32(0.0), jnp.ones(1, bool),
+                                   SchedulerConfig())
     assert int(out.host[0]) == 1
 
 
@@ -93,10 +95,11 @@ def test_aggregate_skips_unusable_hosts_zero_footprint(flag):
 
 def test_schedulers_leave_task_pending_when_no_host_usable():
     hosts = make_host_table(2, 2)._replace(up=jnp.zeros(2, bool))
-    for fn in (schedule_first_fit, schedule_aggregate):
-        out = fn(_zero_footprint_task(), hosts, jnp.float32(0.0),
-                 jnp.ones(1, bool), SchedulerConfig())
-        assert int(out.status[0]) == PENDING, fn.__name__
+    for mode in ("first_fit", "aggregate"):
+        out, _, _ = schedule_step(_zero_footprint_task(), hosts,
+                                  jnp.float32(0.0), jnp.ones(1, bool),
+                                  SchedulerConfig(mode=mode))
+        assert int(out.status[0]) == PENDING, mode
 
 
 # ---------------------------------------------------------------------------

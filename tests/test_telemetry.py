@@ -16,6 +16,7 @@ Four contracts:
 from __future__ import annotations
 
 import dataclasses
+import glob
 import json
 import os
 
@@ -170,6 +171,24 @@ class TestSpans:
             names = [e["name"] for e in tel.events]
         assert "grid.build" in names
         assert names.count("grid.chunk") == 2
+
+    def test_span_reaches_the_profiler_host_plane(self, tmp_path):
+        """A host span is also a profiler TraceAnnotation, so a profiled
+        run's idle gaps can be named after the program's own spans."""
+        from jax.profiler import ProfileData
+        logdir = str(tmp_path / "prof")
+        with telemetry.session(out_dir=str(tmp_path), export=False):
+            with jax.profiler.trace(logdir):
+                with telemetry.span("steam.test_span"):
+                    jnp.arange(8.0).sum().block_until_ready()
+        paths = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                          recursive=True)
+        assert paths
+        names = {ev.name
+                 for plane in ProfileData.from_file(paths[0]).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events}
+        assert "steam.test_span" in names
 
     def test_profile_wraps_jax_profiler(self, tmp_path):
         cfg = _cfg(batt=False)
@@ -404,6 +423,31 @@ class TestRecompileDetector:
         before = w.count
         _cell_fn(7.25)(x).block_until_ready()  # fresh wrapper, same program
         assert w.count >= before
+
+    def test_compile_watch_times_tracing_and_lowering(self):
+        x = jnp.arange(96.0)
+        with telemetry.compile_watch() as w:
+            _cell_fn(9.5)(x).block_until_ready()
+        assert w.trace_seconds > 0.0 and w.lower_seconds > 0.0
+        # the process totals hold at least what the block saw
+        total = telemetry.CompileWatch(since_start=True)
+        assert total.trace_seconds >= w.trace_seconds
+        assert total.lower_seconds >= w.lower_seconds
+
+    def test_a_trace_nested_in_a_later_one_counts_once(self, monkeypatch):
+        """Tracing an outer jit traces its inner jits inside it, and each
+        fires its own duration event when it ends: the clock counts the
+        wall time they cover, not the sum of their durations."""
+        now = iter([10.0, 12.0, 13.0, 20.0])
+        monkeypatch.setattr(telemetry.time, "perf_counter",
+                            lambda: next(now))
+        clock = telemetry._WallClock()
+        clock.add(1.0)    # inner: 9 .. 10
+        clock.add(5.0)    # outer: 7 .. 12, holds the inner
+        assert clock.seconds == 5.0
+        clock.add(0.5)    # 12.5 .. 13, after it
+        clock.add(7.5)    # 12.5 .. 20, overlaps the last one only
+        assert clock.seconds == 5.0 + 7.5
 
 
 # ---------------------------------------------------------------------------

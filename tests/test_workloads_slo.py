@@ -483,10 +483,11 @@ class TestSinglePassScheduler:
         tasks, cfg, now = self._case(seed)
         hosts = make_host_table(int(seed % 3) + 1, 4)
         ok = jnp.ones(tasks.n, bool)
-        plain = schedule_first_fit(tasks, hosts, now, ok, cfg)
+        plain, _, _ = schedule_first_fit(tasks, hosts, now, ok, cfg)
         order = priority_schedule_order(tasks, cfg.priority_levels)
-        pre = schedule_first_fit(permute_task_table(tasks, order), hosts,
-                                 now, ok[order], cfg, presorted=True)
+        pre, _, _ = schedule_first_fit(permute_task_table(tasks, order),
+                                       hosts, now, ok[order], cfg,
+                                       presorted=True)
         pre = permute_task_table(pre, inverse_permutation(order))
         for name in ("status", "host", "first_start", "remaining"):
             np.testing.assert_array_equal(
@@ -500,8 +501,8 @@ class TestSinglePassScheduler:
         most once, higher classes never displaced by lower ones."""
         tasks, cfg, now = self._case(seed)
         hosts = make_host_table(1, 10_000)  # capacity never binds
-        out = schedule_first_fit(tasks, hosts, now,
-                                 jnp.ones(tasks.n, bool), cfg)
+        out, _, _ = schedule_first_fit(tasks, hosts, now,
+                                       jnp.ones(tasks.n, bool), cfg)
         placed = np.asarray(out.status) == RUNNING
         elig = np.asarray(tasks.arrival) <= float(now)
         idx = np.nonzero(elig)[0]
