@@ -20,29 +20,48 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import device
 from . import telemetry as telemetry_mod
 from .config import SchedulerConfig
 from .state import HostTable, TaskTable, PENDING, RUNNING
 
 
-# Below this host count, per-host sums run as a one-hot matmul instead of
-# segment_sum: XLA's CPU scatter path costs ~50us per call at N=1024, which
-# dominated the whole scan step (the sums run EVERY step, inside the hot
-# loop), while the [h, N] matmul is tens of FLOPs per task.  Above it the
-# one-hot mask's h*N footprint stops paying for itself.
+# The host CPU's rule: up to this host count the per-host sums run as a
+# one-hot matmul, above it as segment_sum.  XLA's CPU scatter costs ~50us
+# per call at N=1024, which dominated the scan step (the sums run EVERY
+# step, inside the hot loop), while the [h, N] matmul is tens of FLOPs per
+# task; above 256 hosts the CPU's matmul work, h*N, stops paying for itself.
+# The TPU has no such threshold.  There segment_sum lowers to a scatter-add
+# that serializes over the task rows (on a TPU v5e at 93,587 tasks: 0.82 ms
+# per column, ~450x the time it takes to read its inputs), while the
+# one-hot contraction fuses its iota-compare into one matrix-unit pass and
+# never writes the one-hot to HBM.
 _MATMUL_MAX_HOSTS = 256
 
 
-def _per_host_sum(vals, seg, h: int):
-    """segment_sum(vals, seg, h), scatter-free for small host counts.
+def per_host_sum_form(h: int) -> str:
+    """The form the per-host sums take on the platform the call runs on:
+    "one_hot" (a contraction with the host one-hot) on the TPU at every
+    host count and elsewhere up to `_MATMUL_MAX_HOSTS`, else
+    "segment_sum"."""
+    if device.call_platform() == "tpu" or h <= _MATMUL_MAX_HOSTS:
+        return "one_hot"
+    return "segment_sum"
 
-    Exact for integer-valued inputs (core/GPU counts) in any order; for
-    float-weighted inputs the summation order differs from segment_sum by
-    ULP-level rounding only.  HIGHEST precision keeps the f32 values f32 on
-    the TPU's matrix unit, whose default rounds operands to bf16.
+
+def _per_host_sum(vals, seg, h: int):
+    """`segment_sum(vals, seg, h)` of a `[T, C]` stack of columns: `[H, C]`.
+
+    One call builds the host one-hot once for all C columns.  Exact for
+    integer-valued columns (core/GPU counts) in any order; float columns
+    differ from segment_sum in summation order only.  HIGHEST precision
+    keeps the f32 values f32 on the TPU's matrix unit, whose default rounds
+    operands to bf16 (the one-hot itself is exact in bf16).
     """
-    if h <= _MATMUL_MAX_HOSTS:
-        onehot = (seg[None, :] == jnp.arange(h, dtype=seg.dtype)[:, None])
+    form = per_host_sum_form(h)
+    telemetry_mod.note_per_host_sum(form)
+    if form == "one_hot":
+        onehot = seg[None, :] == jnp.arange(h, dtype=seg.dtype)[:, None]
         return jnp.matmul(onehot.astype(vals.dtype), vals,
                           precision=jax.lax.Precision.HIGHEST)
     return jax.ops.segment_sum(vals, seg, h)
@@ -56,10 +75,11 @@ def free_capacity(tasks: TaskTable, hosts: HostTable):
     # silently billed to host 0
     running = (tasks.status == RUNNING) & (tasks.host >= 0)
     seg = jnp.clip(tasks.host, 0, h - 1)
-    used_c = _per_host_sum(jnp.where(running, tasks.cores, 0.0), seg, h)
-    used_g = _per_host_sum(jnp.where(running, tasks.gpus, 0.0), seg, h)
+    used = _per_host_sum(
+        jnp.where(running[:, None],
+                  jnp.stack([tasks.cores, tasks.gpus], axis=1), 0.0), seg, h)
     avail = (hosts.active & hosts.up).astype(jnp.float32)
-    return hosts.cores * avail - used_c, hosts.n_gpus * avail - used_g
+    return hosts.cores * avail - used[:, 0], hosts.n_gpus * avail - used[:, 1]
 
 
 def host_utilization(tasks: TaskTable, hosts: HostTable):
@@ -67,10 +87,12 @@ def host_utilization(tasks: TaskTable, hosts: HostTable):
     h = hosts.cores.shape[0]
     running = (tasks.status == RUNNING) & (tasks.host >= 0)
     seg = jnp.clip(tasks.host, 0, h - 1)
-    cpu = _per_host_sum(
-        jnp.where(running, tasks.cores * tasks.cpu_util, 0.0), seg, h)
-    gpu = _per_host_sum(
-        jnp.where(running, tasks.gpus * tasks.gpu_util, 0.0), seg, h)
+    busy = _per_host_sum(
+        jnp.where(running[:, None],
+                  jnp.stack([tasks.cores * tasks.cpu_util,
+                             tasks.gpus * tasks.gpu_util], axis=1), 0.0),
+        seg, h)
+    cpu, gpu = busy[:, 0], busy[:, 1]
     cpu_u = jnp.where(hosts.cores > 0, cpu / jnp.maximum(hosts.cores, 1e-6), 0.0)
     gpu_u = jnp.where(hosts.n_gpus > 0, gpu / jnp.maximum(hosts.n_gpus, 1e-6), 0.0)
     return jnp.clip(cpu_u, 0.0, 1.0), jnp.clip(gpu_u, 0.0, 1.0)

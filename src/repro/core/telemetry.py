@@ -274,6 +274,7 @@ class RunRecord:
     execute_time_s: float
     compiles: int
     pallas_interpret: Optional[bool] = None
+    per_host_sum: Optional[str] = None   # "one_hot" | "segment_sum"
     grid_shape: Optional[list] = None
     chunk: Optional[dict] = None    # chunk plan: predicted vs actual bytes
     mesh: Optional[dict] = None
@@ -310,6 +311,7 @@ class Telemetry:
         self.events: list = []          # Chrome-trace events
         self.records: list = []         # RunRecords emitted this session
         self.last_pallas_interpret: Optional[bool] = None
+        self.last_per_host_sum: Optional[str] = None
         self._t0 = time.perf_counter()
         self._lock = threading.Lock()
 
@@ -439,6 +441,13 @@ def note_pallas_interpret(interpret: bool) -> None:
         tel.last_pallas_interpret = bool(interpret)
 
 
+def note_per_host_sum(form: str) -> None:
+    """Record the form the last per-host sum took (core/scheduler.py hook)."""
+    tel = _ACTIVE
+    if tel is not None:
+        tel.last_per_host_sum = form
+
+
 def profile(fn, *args, logdir: Optional[str] = None, **kwargs):
     """One-command Perfetto capture: run ``fn`` under ``jax.profiler.trace``.
 
@@ -525,6 +534,7 @@ def run_recorder(kind: str, cfg: Any, **extra: Any):
         execute_time_s=max(wall - compile_s, 0.0),
         compiles=watch.count,
         pallas_interpret=interp,
+        per_host_sum=tel.last_per_host_sum,
         memory=device_memory_watermarks(),
         grid_shape=builder.grid_shape,
         chunk=builder.chunk,

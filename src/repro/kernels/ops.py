@@ -2,9 +2,10 @@
 
 Each op dispatches to the Pallas kernel with the pure-jnp oracle available
 in kernels/ref.py for testing.  Interpret mode is resolved PER CALL from the
-platform the call runs on (`resolved_interpret`): on CPU the kernel body
-executes as traced jnp for validation; on TPU/GPU the real Mosaic kernel
-runs.  Nothing else selects it.
+platform the call runs on (`resolved_interpret`, which reads
+`core.device.call_platform`): on CPU the kernel body executes as traced
+jnp for validation; on TPU/GPU the real Mosaic kernel runs.  Nothing else
+selects it.
 """
 from __future__ import annotations
 
@@ -15,26 +16,18 @@ from . import first_fit as _first_fit
 from . import fused_step as _fused_step
 from . import power_carbon as _power_carbon
 from . import ssd_chunk as _ssd_chunk
-from repro.core import telemetry
+from repro.core import device, telemetry
 from repro.core.config import CoolingConfig, PowerModelConfig
 
 
 def resolved_interpret() -> bool:
     """Should Pallas kernels run in interpret mode?
 
-    Exactly when the platform the call runs on is CPU: the device pinned by
-    `jax.default_device(...)` if one is, otherwise the default backend.
-    Resolved at call time, not import time, so late backend selection
-    (jax.config, distributed init) is honoured.
+    Exactly when the platform the call runs on is CPU
+    (`device.call_platform`: the device pinned by `jax.default_device(...)`
+    if one is, otherwise the default backend), resolved at call time.
     """
-    pinned = jax.config.jax_default_device
-    if pinned is None:
-        platform = jax.default_backend()
-    elif isinstance(pinned, str):
-        platform = pinned
-    else:
-        platform = pinned.platform
-    interp = platform == "cpu"
+    interp = device.call_platform() == "cpu"
     # observability hook: an active telemetry session records how the call
     # resolved (RunRecord.pallas_interpret); no-op — one attr set — when a
     # session is on, free when off

@@ -25,10 +25,12 @@ from repro.core import (BatteryConfig, CoolingConfig, FailureConfig,
                         PricingConfig, RenewableConfig, ResilienceConfig,
                         ScenarioGrid, SchedulerConfig,
                         ShiftingConfig, SimConfig, build_step_inputs,
-                        dyn_axis, make_host_table, make_task_table, simulate,
-                        summarize, sweep_grid, trace_axis, weather_axis)
+                        device, dyn_axis, make_host_table, make_task_table,
+                        simulate, summarize, sweep_grid, trace_axis,
+                        weather_axis)
 from repro.core.engine import BACKENDS, facility_totals_from_flows
-from repro.core.scheduler import _first_k_indices, _per_host_sum
+from repro.core.scheduler import (_first_k_indices, _per_host_sum,
+                                  per_host_sum_form)
 from repro.kernels import ref as ref_mod
 from repro.kernels.fused_step import fused_facility_totals
 from repro.kernels.ops import resolved_interpret
@@ -322,19 +324,78 @@ def test_slots_per_step_dyn_axis_matches_static():
 # satellite: scatter-free scheduler helpers
 # ---------------------------------------------------------------------------
 
-def test_per_host_sum_matches_segment_sum():
-    rng = np.random.default_rng(5)
-    for h in (1, 3, 17):
-        seg = jnp.asarray(rng.integers(0, h, 257), jnp.int32)
-        ints = jnp.asarray(rng.integers(0, 7, 257).astype(np.float32))
-        floats = jnp.asarray(rng.uniform(0, 1, 257).astype(np.float32))
-        np.testing.assert_array_equal(
-            np.asarray(_per_host_sum(ints, seg, h)),
-            np.asarray(jax.ops.segment_sum(ints, seg, h)))
-        np.testing.assert_allclose(
-            np.asarray(_per_host_sum(floats, seg, h)),
-            np.asarray(jax.ops.segment_sum(floats, seg, h)),
-            rtol=1e-6, atol=1e-5)
+@pytest.mark.parametrize("n_cols", [1, 2])
+@pytest.mark.parametrize("h", [1, 3, 17, 256, 257, 277, 972, 1534])
+@pytest.mark.parametrize("platform", ["host", "tpu"])
+def test_per_host_sum_matches_segment_sum(monkeypatch, platform, h, n_cols):
+    """The per-host sum against segment_sum in the form this host's
+    platform takes and in the TPU's (one one-hot contraction at every host
+    count).  Integer columns equal it bitwise, float columns to
+    rounding."""
+    if platform == "tpu":
+        monkeypatch.setattr(device, "call_platform", lambda: "tpu")
+        assert per_host_sum_form(h) == "one_hot"
+    elif jax.default_backend() == "cpu":  # the host CPU's threshold rule
+        assert per_host_sum_form(h) == ("one_hot" if h <= 256
+                                        else "segment_sum")
+    t = max(257, 3 * h)
+    rng = np.random.default_rng(5 + h)
+    seg = jnp.asarray(rng.integers(0, h, t), jnp.int32)
+    ints = rng.integers(0, 7, t).astype(np.float32)
+    floats = rng.uniform(0, 1, t).astype(np.float32)
+    stacks = ([ints[:, None], floats[:, None]] if n_cols == 1
+              else [np.stack([ints, floats], axis=1)])
+    got = np.concatenate(
+        [np.asarray(_per_host_sum(jnp.asarray(v), seg, h)) for v in stacks],
+        axis=1)
+    assert got.shape == (h, 2)
+    np.testing.assert_array_equal(
+        got[:, 0], np.asarray(jax.ops.segment_sum(jnp.asarray(ints), seg, h)))
+    np.testing.assert_allclose(
+        got[:, 1],
+        np.asarray(jax.ops.segment_sum(jnp.asarray(floats), seg, h)),
+        rtol=1e-6)
+
+
+def test_tpu_per_host_sums_simulate_like_host_form(monkeypatch):
+    """Two days of the SURF deployment (277 hosts, above the host CPU's
+    matmul threshold): the TPU's contraction, forced here, against the
+    host's segment_sum.  Placement reads integer sums, so every task
+    starts, lands and finishes identically; energy, carbon and cost read
+    the float utilization sums and move by rounding only."""
+    from repro.workloads.synthetic import make_workload
+    tasks, hosts, _, meta = make_workload("surf", seed=3, horizon_days=2)
+    assert meta["n_hosts"] == 277
+    n = 2 * 96
+    t = np.arange(n) * DT
+    ci = (300 + 150 * np.sin(2 * np.pi * t / 24)).astype(np.float32)
+    price = (0.1 + 0.05 * np.sin(2 * np.pi * t / 24)).astype(np.float32)
+    wb = (15 + 6 * np.sin(2 * np.pi * t / 24)).astype(np.float32)
+    cfg = SimConfig(n_steps=n, backend="megakernel", use_pallas=False,
+                    cooling=CoolingConfig(enabled=True),
+                    pricing=PricingConfig(enabled=True))
+    dyn = {"price_trace": jnp.asarray(price),
+           "wet_bulb_trace": jnp.asarray(wb)}
+
+    def run():
+        final, _ = simulate(tasks, hosts, ci, cfg, dyn=dict(dyn))
+        return final.tasks, summarize(final, cfg)
+
+    assert per_host_sum_form(277) == "segment_sum"
+    host_tasks, host_res = run()
+    monkeypatch.setattr(device, "call_platform", lambda: "tpu")
+    assert per_host_sum_form(277) == "one_hot"
+    tpu_tasks, tpu_res = run()
+    assert float(host_res.n_started) > 0
+    for field in ("status", "host", "first_start", "finish"):
+        np.testing.assert_array_equal(np.asarray(getattr(tpu_tasks, field)),
+                                      np.asarray(getattr(host_tasks, field)),
+                                      err_msg=field)
+    for field in ("it_energy_kwh", "dc_energy_kwh", "total_carbon_kg",
+                  "total_cost"):
+        np.testing.assert_allclose(float(getattr(tpu_res, field)),
+                                   float(getattr(host_res, field)),
+                                   rtol=1e-6, err_msg=field)
 
 
 def test_first_k_indices_matches_reference():

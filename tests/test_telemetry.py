@@ -520,3 +520,23 @@ class TestRunRecords:
         # on the CPU test host the kernel must have resolved to interpret
         assert rec.pallas_interpret is True
         assert rec.use_pallas is True
+
+    @pytest.mark.parametrize("platform,n_hosts,form", [
+        ("cpu", 3, "one_hot"), ("cpu", 300, "segment_sum"),
+        ("tpu", 300, "one_hot")])
+    def test_per_host_sum_form_lands_in_record(self, tmp_path, monkeypatch,
+                                               platform, n_hosts, form):
+        """The form the per-host sums took is in the record: the CPU's
+        matmul-or-scatter threshold, or the TPU's contraction at every host
+        count."""
+        from repro.core import device
+        monkeypatch.setattr(device, "call_platform", lambda: platform)
+        cfg = _cfg(batt=False)
+        with telemetry.session(out_dir=str(tmp_path), export=False) as tel:
+            simulate(TASKS, make_host_table(n_hosts, 4), CI, cfg)
+            rec = tel.records[-1]
+        assert rec.per_host_sum == form
+
+    def test_per_host_sum_note_is_a_no_op_without_session(self):
+        assert not telemetry.enabled()
+        telemetry.note_per_host_sum("one_hot")  # must not raise

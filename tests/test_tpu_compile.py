@@ -1,4 +1,4 @@
-"""The main-path Pallas kernels compile for a TPU v5e, at deployment widths.
+"""The main path compiles for a TPU v5e, at deployment widths.
 
 Every other kernel test runs the Pallas interpreter on the CPU, which
 accepts code the chip's compiler refuses (scalar stores to VMEM, dynamic
@@ -6,7 +6,9 @@ lane reads, unaligned blocks).  Here the TPU compiler installed with JAX
 compiles each kernel for a described `v5e:2x2` topology, with no chip
 attached: the widths are the SURF-calibrated datacenter (277 hosts, 124 days
 = 11,904 steps at 15 min), the Borg one (1,534 hosts: two host tiles) and
-an 8-region fleet.  Nothing runs, so results are checked elsewhere.
+an 8-region fleet.  The scheduler's per-host sums are compiled too, at
+the SURF task count: there they must be one contraction each, with no
+scatter.  Nothing runs, so results are checked elsewhere.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every file.
@@ -14,6 +16,7 @@ process may load the TPU library, and test workers import every file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,13 +24,15 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import (BatteryConfig, CoolingConfig, PricingConfig,
-                        RenewableConfig, SimConfig)
+                        RenewableConfig, SimConfig, device, make_host_table,
+                        make_task_table, scheduler)
 from repro.core.config import PowerModelConfig
 from repro.kernels import ops
 
 SURF_HOSTS = 277
 BORG_HOSTS = 1534
 SURF_STEPS = 11904          # 124 days at dt = 0.25 h
+SURF_TASKS = 93587          # 14 days of the SURF arrival density
 REGIONS = 8
 
 CPU = PowerModelConfig(80.0, 250.0, "sqrt")
@@ -59,10 +64,16 @@ def one_chip(topo):
 
 
 @pytest.fixture
-def compiled(monkeypatch):
-    """Compile `fn` for the chip through the ops layer, kernels compiled
-    (the ops layer would pick the interpreter from this CPU backend)."""
-    monkeypatch.setattr(ops, "resolved_interpret", lambda: False)
+def on_tpu(monkeypatch):
+    """Make the code read the platform it runs on as the TPU: Pallas
+    kernels compile for Mosaic, and the per-host sums take the TPU's form
+    (both would follow this CPU backend otherwise)."""
+    monkeypatch.setattr(device, "call_platform", lambda: "tpu")
+
+
+@pytest.fixture
+def compiled(on_tpu):
+    """Compile `fn` for the chip through the ops layer, kernels compiled."""
 
     def compile_(fn, *shapes):
         exe = jax.jit(fn).lower(*shapes).compile()
@@ -109,3 +120,28 @@ def test_facility_totals_compiles(one_chip, compiled, store):
     rising = jax.ShapeDtypeStruct((SURF_STEPS,), jnp.bool_, sharding=one_chip)
     compiled(lambda *xs: ops.facility_totals(*xs, cfg, trace_store=store),
              *series, rising)
+
+
+def _table_shapes(table, n, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((n,), x.dtype, sharding=sharding),
+        table)
+
+
+@pytest.mark.parametrize("fn", [scheduler.free_capacity,
+                                scheduler.host_utilization])
+@pytest.mark.parametrize("h", [SURF_HOSTS, BORG_HOSTS])
+def test_per_host_sums_compile_to_one_contraction(one_chip, on_tpu, fn, h):
+    """On the TPU a per-host sum is one contraction of the host one-hot
+    with the stacked columns, at every host count (a scatter-add there
+    serializes over the task rows), and the one-hot stays in the fusion:
+    no buffer as large as the task table is written."""
+    tasks = _table_shapes(make_task_table([0.0], [1.0], [1.0]), SURF_TASKS,
+                          one_chip)
+    hosts = _table_shapes(make_host_table(1, 16), h, one_chip)
+    assert scheduler.per_host_sum_form(h) == "one_hot"
+    exe = jax.jit(fn).lower(tasks, hosts).compile()
+    text = exe.as_text()
+    assert not re.findall(r"\bscatter\(", text)
+    assert len(re.findall(r"\b(?:convolution|dot)\(", text)) == 1
+    assert exe.memory_analysis().temp_size_in_bytes < 4 * SURF_TASKS
