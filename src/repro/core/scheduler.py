@@ -70,14 +70,17 @@ def _per_host_sum(vals, seg, h: int):
 def free_capacity(tasks: TaskTable, hosts: HostTable):
     """Recompute per-host free CPU cores and GPUs from the task table."""
     h = hosts.cores.shape[0]
-    # host >= 0 like failures.interrupt_tasks: the clip below is only index
-    # safety — without the mask a RUNNING task carrying host == -1 would be
-    # silently billed to host 0
-    running = (tasks.status == RUNNING) & (tasks.host >= 0)
-    seg = jnp.clip(tasks.host, 0, h - 1)
-    used = _per_host_sum(
-        jnp.where(running[:, None],
-                  jnp.stack([tasks.cores, tasks.gpus], axis=1), 0.0), seg, h)
+    # both per-host sums' scope, nested in the caller's stage
+    with telemetry_mod.stage_scope("stage_per_host_sum"):
+        # host >= 0 like failures.interrupt_tasks: the clip below is only
+        # index safety — without the mask a RUNNING task carrying host == -1
+        # would be silently billed to host 0
+        running = (tasks.status == RUNNING) & (tasks.host >= 0)
+        seg = jnp.clip(tasks.host, 0, h - 1)
+        used = _per_host_sum(
+            jnp.where(running[:, None],
+                      jnp.stack([tasks.cores, tasks.gpus], axis=1), 0.0),
+            seg, h)
     avail = (hosts.active & hosts.up).astype(jnp.float32)
     return hosts.cores * avail - used[:, 0], hosts.n_gpus * avail - used[:, 1]
 
@@ -85,13 +88,14 @@ def free_capacity(tasks: TaskTable, hosts: HostTable):
 def host_utilization(tasks: TaskTable, hosts: HostTable):
     """Per-host CPU/GPU utilization in [0,1] from running tasks."""
     h = hosts.cores.shape[0]
-    running = (tasks.status == RUNNING) & (tasks.host >= 0)
-    seg = jnp.clip(tasks.host, 0, h - 1)
-    busy = _per_host_sum(
-        jnp.where(running[:, None],
-                  jnp.stack([tasks.cores * tasks.cpu_util,
-                             tasks.gpus * tasks.gpu_util], axis=1), 0.0),
-        seg, h)
+    with telemetry_mod.stage_scope("stage_per_host_sum"):
+        running = (tasks.status == RUNNING) & (tasks.host >= 0)
+        seg = jnp.clip(tasks.host, 0, h - 1)
+        busy = _per_host_sum(
+            jnp.where(running[:, None],
+                      jnp.stack([tasks.cores * tasks.cpu_util,
+                                 tasks.gpus * tasks.gpu_util], axis=1), 0.0),
+            seg, h)
     cpu, gpu = busy[:, 0], busy[:, 1]
     cpu_u = jnp.where(hosts.cores > 0, cpu / jnp.maximum(hosts.cores, 1e-6), 0.0)
     gpu_u = jnp.where(hosts.n_gpus > 0, gpu / jnp.maximum(hosts.n_gpus, 1e-6), 0.0)
