@@ -332,9 +332,9 @@ def stage_progress(cfg: SimConfig) -> Stage:
     def fn(state: SimState, ctx: dict):
         tasks = state.tasks
         running = tasks.status == RUNNING
-        # straggler hosts advance work at speed < 1 (host of each task)
-        h = state.hosts.speed.shape[0]
-        speed = state.hosts.speed[jnp.clip(tasks.host, 0, h - 1)]
+        # straggler hosts advance work at speed < 1: the speed of each
+        # task's host, copied to the row when the scheduler placed it
+        speed = tasks.speed
         if resil:  # thermal throttle computed from the PREVIOUS step
             speed = speed * state.throttle
         advance = cfg.dt_h * jnp.where(running, speed, 1.0)

@@ -207,6 +207,7 @@ def cross_region_spill(tasks: TaskTable, hosts: HostTable,
             job_class=move(t_.job_class, 0), priority=move(t_.priority, 0),
             shiftable=move(t_.shiftable, True),
             sla_grace=move(t_.sla_grace, -1.0),
+            speed=move(t_.speed, 1.0),
         )
         # the moved row keeps status PENDING at the target (move() copied
         # it), so the target region's scheduler picks it up next step

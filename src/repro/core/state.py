@@ -70,6 +70,8 @@ class TaskTable(NamedTuple):
     priority: jax.Array       # i32[T] scheduling priority, higher first
     shiftable: jax.Array      # bool[T] may temporal shifting delay/pause it?
     sla_grace: jax.Array      # f32[T] per-task SLA grace hours; <0 = cfg default
+    speed: jax.Array          # f32[T] speed of the host a RUNNING task was
+                              #   placed on, written at placement (1.0 before)
 
     @property
     def n(self) -> int:
@@ -186,7 +188,7 @@ def make_task_table(arrival, duration, cores, gpus=None, cpu_util=None,
         host=jnp.full(t, -1, jnp.int32), first_start=inf, finish=inf,
         lost_work=jnp.zeros(t, jnp.float32),
         job_class=job_class, priority=priority, shiftable=shiftable,
-        sla_grace=sla_grace,
+        sla_grace=sla_grace, speed=jnp.ones(t, jnp.float32),
     )
 
 
@@ -306,6 +308,7 @@ def pad_task_table(tasks: TaskTable, n: int) -> TaskTable:
         priority=_pad(tasks.priority, 0),
         shiftable=_pad(tasks.shiftable, True),
         sla_grace=_pad(tasks.sla_grace, -1.0),
+        speed=_pad(tasks.speed, 1.0),
     )
 
 

@@ -398,3 +398,94 @@ def test_straggler_hosts_slow_tasks_and_hurt_sla():
                                straggler_speed=0.4)
     res_b, _, _ = run(tasks, slow_big, jnp.asarray(ci), cfg)
     assert float(res_b.mean_delay_h) <= float(res_s.mean_delay_h) + 1e-6
+
+
+def _host_table_progress(cfg):
+    """The progress stage as it was before placement wrote each task's
+    host speed to the task table: every step it gathered every row's speed
+    from the host table.  The oracle for the speed column."""
+    resil = cfg.resilience.enabled
+
+    def fn(state, ctx):
+        tasks = state.tasks
+        running = tasks.status == RUNNING
+        h = state.hosts.speed.shape[0]
+        speed = state.hosts.speed[jnp.clip(tasks.host, 0, h - 1)]
+        if resil:
+            speed = speed * state.throttle
+        advance = cfg.dt_h * jnp.where(running, speed, 1.0)
+        done_now = running & (tasks.remaining <= advance)
+        finish = jnp.where(done_now,
+                           state.t + tasks.remaining / jnp.maximum(speed, 1e-6),
+                           tasks.finish)
+        remaining = jnp.where(running,
+                              jnp.maximum(tasks.remaining - advance, 0.0),
+                              tasks.remaining)
+        tasks = tasks._replace(
+            remaining=remaining, finish=finish,
+            status=jnp.where(done_now, DONE, tasks.status).astype(jnp.int32),
+            host=jnp.where(done_now, -1, tasks.host).astype(jnp.int32))
+        return state._replace(tasks=tasks), ctx
+    return fn
+
+
+@pytest.mark.parametrize("backend,mode", [("stage-pipeline", "first_fit"),
+                                          ("megakernel", "first_fit"),
+                                          ("stage-pipeline", "aggregate")])
+def test_placed_speed_column_is_the_host_speed(backend, mode, monkeypatch):
+    """Placement copies the host's speed into the task row: after every
+    step each RUNNING row's `speed` is bitwise `hosts.speed[host]`, under
+    straggler hosts, failures (requeue) and the task stopper (pause), and
+    a run's finish times and remaining work are bitwise those of the old
+    per-step host-table gather, in both scheduler modes."""
+    from repro.core import engine
+    n_tasks, n = 48, 24 * 4 * 4
+    rng = np.random.default_rng(11)
+    tasks = make_task_table(np.sort(rng.uniform(0.0, 48.0, n_tasks)),
+                            rng.uniform(1.0, 10.0, n_tasks),
+                            rng.integers(1, 5, n_tasks).astype(float))
+    hosts = make_host_table(6, 8.0, straggler_frac=0.5, straggler_speed=0.4,
+                            seed=1)
+    host_speed = np.asarray(hosts.speed)
+    assert np.any(host_speed == np.float32(0.4)) and np.any(host_speed == 1.0)
+    cfg = SimConfig(n_steps=n, backend=backend,
+                    scheduler=SchedulerConfig(mode=mode),
+                    failures=FailureConfig(enabled=True, mtbf_h=20.0,
+                                           repair_h=2.0),
+                    shifting=ShiftingConfig(enabled=True, stop_running=True,
+                                            forecast_window_h=24.0,
+                                            max_delay_h=12.0))
+    trace = square_trace(n, period=48)
+
+    mismatches = []
+    new_progress = engine.stage_progress
+
+    def checked_progress(cfg_):
+        step = new_progress(cfg_)
+
+        def fn(state, ctx):
+            state, ctx = step(state, ctx)
+            t = state.tasks
+            h = state.hosts.speed.shape[0]
+            bad = (t.status == RUNNING) & (
+                t.speed != state.hosts.speed[jnp.clip(t.host, 0, h - 1)])
+            jax.debug.callback(lambda b: mismatches.append(int(b)),
+                               jnp.sum(bad.astype(jnp.int32)))
+            return state, ctx
+        return fn
+
+    monkeypatch.setattr(engine, "stage_progress", checked_progress)
+    final, _ = simulate(tasks, hosts, trace, cfg)
+    jax.block_until_ready(final)
+    assert len(mismatches) == n and sum(mismatches) == 0
+    assert float(final.metrics.n_interrupts) > 0
+    assert float(final.metrics.n_stops) > 0
+    assert np.any(np.asarray(final.tasks.speed) == np.float32(0.4))
+
+    monkeypatch.setattr(engine, "stage_progress", _host_table_progress)
+    oracle, _ = simulate(tasks, hosts, trace, cfg)
+    for col in ("finish", "remaining", "status", "first_start"):
+        np.testing.assert_array_equal(np.asarray(getattr(final.tasks, col)),
+                                      np.asarray(getattr(oracle.tasks, col)),
+                                      err_msg=col)
+    assert np.isfinite(np.asarray(final.tasks.finish)).sum() > n_tasks // 2

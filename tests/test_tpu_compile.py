@@ -8,7 +8,8 @@ attached: the widths are the SURF-calibrated datacenter (277 hosts, 124 days
 = 11,904 steps at 15 min), the Borg one (1,534 hosts: two host tiles) and
 an 8-region fleet.  The scheduler's per-host sums are compiled too, at
 the SURF task count: there they must be one contraction each, with no
-scatter.  Nothing runs, so results are checked elsewhere.
+scatter; and the progress stage, which must gather no task-wide column
+from the host table.  Nothing runs, so results are checked elsewhere.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every file.
@@ -24,8 +25,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import (BatteryConfig, CoolingConfig, PricingConfig,
-                        RenewableConfig, SimConfig, device, make_host_table,
-                        make_task_table, scheduler)
+                        RenewableConfig, SimConfig, device, engine,
+                        init_sim_state, make_host_table, make_task_table,
+                        scheduler)
 from repro.core.config import PowerModelConfig
 from repro.kernels import ops
 
@@ -145,3 +147,23 @@ def test_per_host_sums_compile_to_one_contraction(one_chip, on_tpu, fn, h):
     assert not re.findall(r"\bscatter\(", text)
     assert len(re.findall(r"\b(?:convolution|dot)\(", text)) == 1
     assert exe.memory_analysis().temp_size_in_bytes < 4 * SURF_TASKS
+
+
+@pytest.mark.parametrize("h", [SURF_HOSTS, BORG_HOSTS])
+def test_progress_gathers_no_task_rows_from_the_host_table(one_chip, on_tpu,
+                                                           h):
+    """The progress stage reads each running task's host speed from the
+    task table, where placement wrote it: no gather as wide as the task
+    table (XLA's general gather emitter, one host-table read per row)."""
+    state = init_sim_state(make_task_table([0.0], [1.0], [1.0]),
+                           make_host_table(1, 16))
+    state = state._replace(
+        tasks=_table_shapes(state.tasks, SURF_TASKS, one_chip),
+        hosts=_table_shapes(state.hosts, h, one_chip))
+    state = jax.tree.map(
+        lambda x: x if isinstance(x, jax.ShapeDtypeStruct) else
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), state)
+    step = engine.stage_progress(SimConfig())
+    text = jax.jit(lambda s: step(s, {})[0].tasks).lower(
+        state).compile().as_text()
+    assert not re.findall(rf"= \w+\[{SURF_TASKS}\]\S* gather\(", text)
