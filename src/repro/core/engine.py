@@ -52,15 +52,23 @@ money but no carbon credit (location-based accounting).
 
 Kernel backends
 ---------------
-`cfg.backend` selects the step executor:
+`cfg.backend` selects the step executor.  Every executor shares one
+assembly, written once in this module: `prepare_run` (`simulate`'s front
+half: dyn checks, task-table shaping, `StepInputs`, initial state),
+`step_ctx` (a step's ctx from its inputs), `_run_stages` (the scoped
+stage loop) and `_series` (the `collect_series` outputs).  The stages
+themselves are the same functions on every path.
 
   * ``stage-pipeline`` (default) — the scan above: one `lax.scan` whose
-    step runs every stage, dragging the full task/host tables through all
-    S steps.  Maximum composability (custom `stages` land here).
+    step (`build_step_fn`) runs every stage, dragging the full task/host
+    tables through all S steps.  Maximum composability (custom `stages`
+    land here).  The fleet spill executor (core/fleet.py) vmaps
+    `prepare_run` and this step over regions.
   * ``megakernel`` — the same simulation split at its one true sequential
-    boundary.  The DEMAND phase (failures -> stopper -> scheduler ->
-    progress -> IT power) still scans, because placement is genuinely
-    recurrent; it emits only `it_kw[S]`.  The FACILITY phase (cooling ->
+    boundary.  The DEMAND phase (`_build_demand_step`: `demand_stages`,
+    then `stage_it_power` and, with resilience on, `stage_resilience`)
+    still scans, because placement is genuinely recurrent; it emits only
+    `it_kw[S]`.  The FACILITY phase (cooling ->
     renewables -> battery -> pricing -> carbon) is elementwise in t except
     for two scalar recurrences (battery SoC, billing-window peak), so it
     runs as [S]-wide vector math with a scalar-carry scan
@@ -110,10 +118,12 @@ from . import scheduler as scheduler_mod
 from . import shifting as shifting_mod
 from . import telemetry as telemetry_mod
 from . import thermal as thermal_mod
-from .config import SimConfig
+from .config import HOURS_PER_YEAR, SimConfig
 from .power import host_power_kw
 from .state import (DONE, PENDING, RUNNING, BatteryState, HostTable,
-                    MetricsAcc, SimState, TaskTable, init_sim_state)
+                    SimState, TaskTable, init_sim_state, inverse_permutation,
+                    permute_task_table, priority_schedule_order,
+                    retime_task_table, with_interactive_frac)
 
 BACKENDS = ("stage-pipeline", "megakernel")
 
@@ -353,14 +363,18 @@ def stage_progress(cfg: SimConfig) -> Stage:
     return fn
 
 
-def stage_power(cfg: SimConfig) -> Stage:
-    """Writes `flow.it_kw` (and provisionally `flow.grid_import_kw`: with no
-    later facility stage, the IT draw IS the metered import).
+def stage_it_power(cfg: SimConfig) -> Stage:
+    """IT power: per-host utilization, per-host power (the Pallas kernel
+    with `cfg.use_pallas`), and their sum.  Writes `flow.it_kw` (and
+    provisionally `flow.grid_import_kw`: with no later facility stage, the
+    IT draw IS the metered import).
 
     With resilience on, the previous step's thermal throttle caps host
     utilization and the PDU failure process clamps the summed IT draw
     (`flow.it_kw` is the CAPPED value every downstream consumer meters;
-    the raw demand is kept in ctx for the next-throttle rule)."""
+    the raw demand is kept in ctx `raw_it_kw` for the next-throttle rule).
+    `stage_power` runs it on the stage pipeline, the megakernel's demand
+    step on its own."""
     resil = cfg.resilience.enabled
 
     def fn(state: SimState, ctx: dict):
@@ -369,25 +383,8 @@ def stage_power(cfg: SimConfig) -> Stage:
             cpu_u = cpu_u * state.throttle
             gpu_u = gpu_u * state.throttle
         on = (state.hosts.active & state.hosts.up).astype(jnp.float32)
-        if cfg.collect_series:  # capacity-invariant probe for tests/debugging
-            free_c, free_g = scheduler_mod.free_capacity(state.tasks, state.hosts)
-            ctx["max_overcommit"] = jnp.maximum(jnp.max(-free_c), jnp.max(-free_g))
         if cfg.use_pallas:
             from repro.kernels import ops as pc_ops
-            if cfg.cooling.enabled and not resil:
-                # one VMEM pass: per-host power + IT sum + cooling + water.
-                # (not with resilience: the PDU clamp sits between the IT sum
-                # and the cooling model, splitting the fused op in two)
-                sp = ctx.get("cooling_setpoint", cfg.cooling.setpoint_c)
-                p, it_kw, cool_kw, water = pc_ops.facility_power(
-                    cpu_u, gpu_u, state.hosts.n_gpus, on, ctx["wet_bulb_c"],
-                    sp, cfg.cpu_power, cfg.gpu_power, cfg.cooling)
-                flow = ctx["flow"]._replace(it_kw=it_kw, grid_import_kw=it_kw)
-                ctx = dict(ctx, flow=flow, host_power_kw=p,
-                           host_cpu_util=cpu_u, host_gpu_util=gpu_u,
-                           fused_cooling_kw=cool_kw,
-                           fused_water_l_per_h=water)
-                return state, ctx
             p = pc_ops.host_power(cpu_u, gpu_u, state.hosts.n_gpus, on,
                                   cfg.cpu_power, cfg.gpu_power)
         else:
@@ -400,6 +397,45 @@ def stage_power(cfg: SimConfig) -> Stage:
         flow = ctx["flow"]._replace(it_kw=it_kw, grid_import_kw=it_kw)
         ctx = dict(ctx, flow=flow, host_power_kw=p,
                    host_cpu_util=cpu_u, host_gpu_util=gpu_u)
+        return state, ctx
+    return fn
+
+
+def _max_overcommit(state: SimState) -> jax.Array:
+    """Largest overcommit of any host's cores or GPUs: a capacity-invariant
+    probe for tests and debugging (`collect_series`)."""
+    free_c, free_g = scheduler_mod.free_capacity(state.tasks, state.hosts)
+    return jnp.maximum(jnp.max(-free_c), jnp.max(-free_g))
+
+
+def stage_power(cfg: SimConfig) -> Stage:
+    """`stage_it_power`, or with Pallas and cooling on and resilience off,
+    one fused kernel that also computes the cooling draw (`stage_cooling`
+    reads it from ctx).  Under `collect_series` it also records
+    `_max_overcommit`."""
+    fused = (cfg.use_pallas and cfg.cooling.enabled
+             and not cfg.resilience.enabled)
+    it_power = stage_it_power(cfg)
+
+    def fn(state: SimState, ctx: dict):
+        if cfg.collect_series:
+            ctx["max_overcommit"] = _max_overcommit(state)
+        if not fused:
+            return it_power(state, ctx)
+        # one VMEM pass: per-host power + IT sum + cooling + water.  (not
+        # with resilience: the PDU clamp sits between the IT sum and the
+        # cooling model, splitting the fused op in two)
+        from repro.kernels import ops as pc_ops
+        cpu_u, gpu_u = scheduler_mod.host_utilization(state.tasks, state.hosts)
+        on = (state.hosts.active & state.hosts.up).astype(jnp.float32)
+        sp = ctx.get("cooling_setpoint", cfg.cooling.setpoint_c)
+        p, it_kw, cool_kw, water = pc_ops.facility_power(
+            cpu_u, gpu_u, state.hosts.n_gpus, on, ctx["wet_bulb_c"],
+            sp, cfg.cpu_power, cfg.gpu_power, cfg.cooling)
+        flow = ctx["flow"]._replace(it_kw=it_kw, grid_import_kw=it_kw)
+        ctx = dict(ctx, flow=flow, host_power_kw=p,
+                   host_cpu_util=cpu_u, host_gpu_util=gpu_u,
+                   fused_cooling_kw=cool_kw, fused_water_l_per_h=water)
         return state, ctx
     return fn
 
@@ -558,26 +594,28 @@ def stage_pricing(cfg: SimConfig) -> Stage:
     return fn
 
 
+def _batt_embodied_rate(cfg: SimConfig, capacity_kwh=None):
+    """Battery embodied carbon per hour of ownership, kg/h: for the traced
+    `batt_capacity_kwh` where one is given, else for the configured size."""
+    if capacity_kwh is None or not cfg.battery.enabled:
+        return battery_mod.battery_embodied_rate_kg_per_h(cfg.battery)
+    return (capacity_kwh * cfg.battery.embodied_kg_per_kwh
+            / (cfg.battery.lifetime_years * HOURS_PER_YEAR))
+
+
 def stage_carbon(cfg: SimConfig) -> Stage:
     """Carbon + energy accounting off the settled ledger: operational
     carbon, grid energy and the tracked peak all meter
     `flow.grid_import_kw` — with renewables on, the NET import (on-site
     generation displaces carbon one-for-one; exports earn no credit under
     location-based accounting)."""
-    static_batt_rate = battery_mod.battery_embodied_rate_kg_per_h(cfg.battery)
     renew = cfg.renewables.enabled
 
     def fn(state: SimState, ctx: dict):
         flow = ctx["flow"]
         grid_kw = flow.grid_import_kw
         n_active = jnp.sum(state.hosts.active.astype(jnp.float32))
-        cap = ctx.get("batt_capacity_kwh")
-        if cap is not None and cfg.battery.enabled:
-            from .config import HOURS_PER_YEAR
-            batt_rate = (cap * cfg.battery.embodied_kg_per_kwh
-                         / (cfg.battery.lifetime_years * HOURS_PER_YEAR))
-        else:
-            batt_rate = static_batt_rate
+        batt_rate = _batt_embodied_rate(cfg, ctx.get("batt_capacity_kwh"))
         op, emb = carbon_mod.carbon_delta(grid_kw, ctx["ci"], cfg.dt_h,
                                           n_active, cfg.embodied, batt_rate)
         m = state.metrics
@@ -604,7 +642,9 @@ def stage_resilience(cfg: SimConfig) -> Stage:
     compute the throttle the NEXT step will run under (one-step delay =
     causal recurrence; see core/resilience.next_throttle), and account the
     resilience metrics (hours throttled / hours with facility equipment
-    derated).  Runs last so it sees the capped `flow.it_kw`."""
+    derated).  Runs once the IT draw is settled (last on the stage
+    pipeline, right after `stage_it_power` in the megakernel's demand step),
+    so it sees the capped `flow.it_kw`."""
     rcfg = cfg.resilience
     dt = jnp.float32(cfg.dt_h)
 
@@ -628,12 +668,11 @@ def stage_resilience(cfg: SimConfig) -> Stage:
     return fn
 
 
-def default_pipeline(cfg: SimConfig) -> list[Stage]:
-    """Technique composition: each enabled technique contributes its stage.
-
-    Mirrors paper Fig 4 — adding the task stopper or the battery touches only
-    its own stage; everything else is unchanged.
-    """
+def demand_stages(cfg: SimConfig) -> list[Stage]:
+    """The recurrent head of every step: checkpoint -> failures -> task
+    stopper -> scheduler -> progress, each where its technique is on.
+    `default_pipeline` starts with it; the megakernel's demand scan runs
+    it alone."""
     stages: list[Stage] = []
     if cfg.failures.enabled:
         # checkpoint BEFORE failures: the boundary snapshot at time t must
@@ -646,7 +685,16 @@ def default_pipeline(cfg: SimConfig) -> list[Stage]:
         stages.append(stage_failures(cfg))
     if cfg.shifting.enabled and cfg.shifting.stop_running:
         stages.append(stage_task_stopper(cfg))
-    stages += [stage_scheduler(cfg), stage_progress(cfg), stage_power(cfg)]
+    return stages + [stage_scheduler(cfg), stage_progress(cfg)]
+
+
+def default_pipeline(cfg: SimConfig) -> list[Stage]:
+    """Technique composition: each enabled technique contributes its stage.
+
+    Mirrors paper Fig 4 — adding the task stopper or the battery touches only
+    its own stage; everything else is unchanged.
+    """
+    stages = demand_stages(cfg) + [stage_power(cfg)]
     if cfg.cooling.enabled:
         stages.append(stage_cooling(cfg))
     if cfg.renewables.enabled:
@@ -717,46 +765,57 @@ def _stage_label(stage: Stage) -> str:
     return q.split(".<locals>")[0] or getattr(stage, "__name__", "stage")
 
 
+def step_ctx(inputs: StepInputs, dyn: dict) -> dict:
+    """A step's ctx: its exogenous inputs under their `StepInputs` field
+    names, an empty energy-flow ledger, and the traced `dyn` values."""
+    return {**inputs._asdict(), "flow": init_energy_flow(), **dyn}
+
+
+def _run_stages(stages: Sequence[Stage], state: SimState, ctx: dict):
+    """Run `stages` in order, each under its own stage scope."""
+    for stage in stages:
+        with telemetry_mod.stage_scope(_stage_label(stage)):
+            state, ctx = stage(state, ctx)
+    return state, ctx
+
+
+def _series(cfg: SimConfig, flow: EnergyFlow, ci, n_running, soc,
+            max_overcommit, wet_bulb_c, price) -> dict:
+    """The `collect_series` outputs, from one step's values (stage
+    pipeline) or from [S] series (megakernel)."""
+    ys = {"grid_power_kw": flow.grid_import_kw,
+          "dc_power_kw": flow.it_kw + flow.cooling_kw,
+          "ci": ci, "n_running": n_running, "battery_charge": soc,
+          "max_overcommit": max_overcommit, "flow": flow}
+    if cfg.cooling.enabled:
+        ys["cooling_power_kw"] = flow.cooling_kw
+        ys["wet_bulb_c"] = wet_bulb_c
+    if cfg.pricing.enabled:
+        ys["price_per_kwh"] = price
+    return ys
+
+
 def build_step_fn(cfg: SimConfig, stages: Sequence[Stage] | None = None,
                   dyn: dict | None = None):
+    """The stage pipeline's scan step `(state, StepInputs) -> (state, ys)`:
+    `stages` (default: `default_pipeline`, plus the probe sampler with
+    probes on) over `step_ctx`, then the clock tick; `ys` is `_series`
+    under `collect_series`, else None."""
     stages = default_pipeline(cfg) if stages is None else list(stages)
     if cfg.probes.enabled:
         stages.append(stage_probes(cfg))
     dyn = dyn or {}
 
     def step(state: SimState, inputs: StepInputs):
-        ctx = {"ci": inputs.ci, "batt_threshold": inputs.batt_threshold,
-               "ci_rising": inputs.ci_rising,
-               "shift_threshold": inputs.shift_threshold,
-               "wet_bulb_c": inputs.wet_bulb_c, "price": inputs.price,
-               "price_lo": inputs.price_lo, "price_hi": inputs.price_hi,
-               "pv_cf": inputs.pv_cf,
-               "chiller_derate": inputs.chiller_derate,
-               "pdu_cap_kw": inputs.pdu_cap_kw,
-               "flow": init_energy_flow(),
-               **dyn}
-        for stage in stages:
-            with telemetry_mod.stage_scope(_stage_label(stage)):
-                state, ctx = stage(state, ctx)
+        state, ctx = _run_stages(stages, state, step_ctx(inputs, dyn))
         state = _advance_clock(state, cfg)
-        if cfg.collect_series:
-            flow: EnergyFlow = ctx["flow"]
-            ys = {"grid_power_kw": flow.grid_import_kw,
-                  "dc_power_kw": flow.it_kw + flow.cooling_kw,
-                  "ci": ctx["ci"],
-                  "n_running": jnp.sum((state.tasks.status == RUNNING)
-                                       .astype(jnp.int32)),
-                  "battery_charge": state.battery.charge,
-                  "max_overcommit": ctx.get("max_overcommit", jnp.float32(0.0)),
-                  "flow": flow}
-            if cfg.cooling.enabled:
-                ys["cooling_power_kw"] = flow.cooling_kw
-                ys["wet_bulb_c"] = ctx["wet_bulb_c"]
-            if cfg.pricing.enabled:
-                ys["price_per_kwh"] = ctx["price"]
-        else:
-            ys = None
-        return state, ys
+        if not cfg.collect_series:
+            return state, None
+        n_running = jnp.sum((state.tasks.status == RUNNING).astype(jnp.int32))
+        return state, _series(
+            cfg, ctx["flow"], ctx["ci"], n_running, state.battery.charge,
+            ctx.get("max_overcommit", jnp.float32(0.0)), ctx["wet_bulb_c"],
+            ctx["price"])
 
     return step
 
@@ -766,86 +825,35 @@ def build_step_fn(cfg: SimConfig, stages: Sequence[Stage] | None = None,
 # --------------------------------------------------------------------------
 
 def _build_demand_step(cfg: SimConfig, dyn: dict):
-    """Scan step for the megakernel DEMAND phase: the genuinely recurrent
-    stages (failures -> stopper -> scheduler -> progress) plus an IT-power
-    probe.  Emits per-step `it_kw` — the only demand->facility coupling —
-    and, under `collect_series`, the capacity/occupancy probes the
-    stage-pipeline series carry.
+    """Scan step for the megakernel DEMAND phase: `demand_stages`, then
+    `stage_it_power` and, with resilience on, `stage_resilience` — the
+    stage pipeline's own stage functions in its order, so the emitted
+    `it_kw[S]` (the capped draw, the only demand->facility coupling)
+    matches it and the facility half stays vectorized.  Also emits, with
+    probes on, the queue depth and the throttle each step ran under, and
+    under `collect_series` the capacity/occupancy probes the stage-pipeline
+    series carry.
 
-    With resilience on, the scan's xs also carry the exogenous facility
-    series (wet-bulb, chiller derate, PDU cap) and the step replicates the
-    stage pipeline's throttle recurrence exactly: previous-step throttle
-    caps utilization, the PDU clamps the IT sum, and the NEXT throttle is
-    computed from the capped draw — same formulas, same order, so the
-    emitted `it_kw[S]` matches the stage pipeline and the facility half
-    stays vectorized (it consumes it_kw and the same exogenous series)."""
-    stages: list[Stage] = []
-    if cfg.failures.enabled:
-        # checkpoint-before-failures, same as default_pipeline
-        if cfg.failures.checkpointing:
-            stages.append(stage_checkpoint(cfg))
-        stages.append(stage_failures(cfg))
-    if cfg.shifting.enabled and cfg.shifting.stop_running:
-        stages.append(stage_task_stopper(cfg))
-    stages += [stage_scheduler(cfg), stage_progress(cfg)]
-    resil = cfg.resilience.enabled
-    rcfg = cfg.resilience
+    Its xs are a `StepInputs` holding only the series these stages read
+    (`_simulate_megakernel`); the others are None, so their ctx keys are
+    None too."""
+    stages = demand_stages(cfg) + [stage_it_power(cfg)]
+    if cfg.resilience.enabled:
+        stages.append(stage_resilience(cfg))
 
-    def step(state: SimState, xs):
-        # defaults cover the xs the enabled techniques don't feed (shifting
-        # off: the gate never reads ci/threshold)
-        ctx = {"ci": jnp.float32(0.0), "shift_threshold": jnp.float32(0.0),
-               **(xs or {}), **dyn}
-        for stage in stages:
-            with telemetry_mod.stage_scope(_stage_label(stage)):
-                state, ctx = stage(state, ctx)
-        with telemetry_mod.stage_scope("stage_it_power"):
-            cpu_u, gpu_u = scheduler_mod.host_utilization(state.tasks,
-                                                          state.hosts)
-            if resil:
-                cpu_u = cpu_u * state.throttle
-                gpu_u = gpu_u * state.throttle
-            on = (state.hosts.active & state.hosts.up).astype(jnp.float32)
-            if cfg.use_pallas:
-                from repro.kernels import ops as pc_ops
-                p = pc_ops.host_power(cpu_u, gpu_u, state.hosts.n_gpus, on,
-                                      cfg.cpu_power, cfg.gpu_power)
-            else:
-                p = host_power_kw(cpu_u, gpu_u, state.hosts.n_gpus, on,
-                                  cfg.cpu_power, cfg.gpu_power)
-            it_kw = jnp.sum(p)
-        # throttle the step RAN under (the probe-bus channel; the recurrence
-        # below replaces state.throttle with the NEXT step's value)
-        applied_throttle = state.throttle if resil else jnp.float32(1.0)
-        if resil:  # mirror stage_power's clamp + stage_resilience's update
-            raw_it_kw = it_kw
-            it_kw = jnp.minimum(it_kw, ctx["pdu_cap_kw"])
-            dt = jnp.float32(cfg.dt_h)
-            m = state.metrics
-            m = m._replace(
-                throttled_h=m.throttled_h
-                + dt * (state.throttle < 1.0).astype(jnp.float32),
-                derate_h=m.derate_h
-                + dt * ((ctx["chiller_derate"] < 1.0)
-                        | jnp.isfinite(ctx["pdu_cap_kw"])).astype(jnp.float32))
-            throttle = resilience_mod.next_throttle(
-                it_kw, raw_it_kw, ctx["wet_bulb_c"], ctx["chiller_derate"],
-                ctx["pdu_cap_kw"], rcfg,
-                threshold_c=ctx.get("throttle_inlet_c"))
-            state = state._replace(metrics=m, throttle=throttle)
+    def step(state: SimState, inputs: StepInputs):
+        state, ctx = _run_stages(stages, state, step_ctx(inputs, dyn))
         # probe-bus queue depth samples the pre-increment time, exactly like
         # the stage pipeline's probe stage (which runs before the increment)
         qd = _queue_depth(state) if cfg.probes.enabled else None
         state = _advance_clock(state, cfg)
-        ys = {"it_kw": it_kw}
+        ys = {"it_kw": ctx["flow"].it_kw}
         if qd is not None:
             ys["queue_depth"] = qd
-            ys["throttle_factor"] = applied_throttle
+            ys["throttle_factor"] = ctx.get("throttle_factor",
+                                            jnp.float32(1.0))
         if cfg.collect_series:
-            free_c, free_g = scheduler_mod.free_capacity(state.tasks,
-                                                         state.hosts)
-            ys["max_overcommit"] = jnp.maximum(jnp.max(-free_c),
-                                               jnp.max(-free_g))
+            ys["max_overcommit"] = _max_overcommit(state)
             ys["n_running"] = jnp.sum((state.tasks.status == RUNNING)
                                       .astype(jnp.int32))
         return state, ys
@@ -909,13 +917,7 @@ def _merge_facility_totals(state: SimState, totals: dict, cfg: SimConfig,
     # during a run (failures toggle `up`), so the per-step accumulation is a
     # closed-form product — the one stage_carbon term with no flow input
     n_active = jnp.sum(state.hosts.active.astype(jnp.float32))
-    cap = dyn.get("batt_capacity_kwh")
-    if cap is not None and cfg.battery.enabled:
-        from .config import HOURS_PER_YEAR
-        batt_rate = (cap * cfg.battery.embodied_kg_per_kwh
-                     / (cfg.battery.lifetime_years * HOURS_PER_YEAR))
-    else:
-        batt_rate = battery_mod.battery_embodied_rate_kg_per_h(cfg.battery)
+    batt_rate = _batt_embodied_rate(cfg, dyn.get("batt_capacity_kwh"))
     host_rate = carbon_mod.host_embodied_rate_kg_per_h(cfg.embodied)
     emb = (n_active * host_rate + batt_rate) * dt * cfg.n_steps
     m = m._replace(
@@ -955,16 +957,19 @@ def _simulate_megakernel(state0: SimState, inputs: StepInputs,
     from repro.kernels import ref as ref_mod  # lazy: kernels import core
 
     step = _build_demand_step(cfg, dyn)
-    xs = {}
+    # only the series the demand stages read ride the scan: the shifting
+    # gate reads the CI trace, the throttle recurrence the facility series.
+    # With neither on the scan has no batched input, so a scenario vmap
+    # hoists it.
+    carried = set()
     if cfg.shifting.enabled:
-        xs["ci"] = inputs.ci
-        xs["shift_threshold"] = inputs.shift_threshold
-    if cfg.resilience.enabled:  # the throttle recurrence reads these
-        xs["wet_bulb_c"] = inputs.wet_bulb_c
-        xs["chiller_derate"] = inputs.chiller_derate
-        xs["pdu_cap_kw"] = inputs.pdu_cap_kw
+        carried |= {"ci", "shift_threshold"}
+    if cfg.resilience.enabled:
+        carried |= {"wet_bulb_c", "chiller_derate", "pdu_cap_kw"}
+    xs = inputs._replace(**{f: None for f in StepInputs._fields
+                            if f not in carried})
     with telemetry_mod.stage_scope("megakernel.demand"):
-        final, demand_ys = jax.lax.scan(step, state0, xs or None,
+        final, demand_ys = jax.lax.scan(step, state0, xs,
                                         length=cfg.n_steps)
     it_series = demand_ys["it_kw"]
 
@@ -1020,26 +1025,65 @@ def _simulate_megakernel(state0: SimState, inputs: StepInputs,
             cfg.n_steps, cfg.probes, series))
     if not cfg.collect_series:
         return final, None
-    flow = EnergyFlow(
-        it_kw=flows["it_kw"], cooling_kw=flows["cooling_kw"],
-        pv_kw=flows["pv_kw"], batt_charge_kw=flows["batt_charge_kw"],
-        batt_discharge_kw=flows["batt_discharge_kw"],
-        grid_import_kw=flows["grid_import_kw"],
-        grid_export_kw=flows["grid_export_kw"],
-        curtailed_kw=flows["curtailed_kw"])
-    ys = {"grid_power_kw": flow.grid_import_kw,
-          "dc_power_kw": flow.it_kw + flow.cooling_kw,
-          "ci": inputs.ci,
-          "n_running": demand_ys["n_running"],
-          "battery_charge": flows["soc"],
-          "max_overcommit": demand_ys["max_overcommit"],
-          "flow": flow}
-    if cfg.cooling.enabled:
-        ys["cooling_power_kw"] = flow.cooling_kw
-        ys["wet_bulb_c"] = inputs.wet_bulb_c
-    if cfg.pricing.enabled:
-        ys["price_per_kwh"] = inputs.price
-    return final, ys
+    flow = EnergyFlow(**{f: flows[f] for f in EnergyFlow._fields})
+    return final, _series(cfg, flow, inputs.ci, demand_ys["n_running"],
+                          flows["soc"], demand_ys["max_overcommit"],
+                          inputs.wet_bulb_c, inputs.price)
+
+
+def prepare_run(tasks: TaskTable, hosts: HostTable, ci_trace, cfg: SimConfig,
+                dyn: dict | None = None, weather_trace=None):
+    """`simulate`'s front half, shared by every executor (the fleet spill
+    executor vmaps it over regions).  Checks `dyn`, applies its
+    table-shaping keys and the priority presort, builds the scan's
+    `StepInputs` and the initial state.  Returns (state0, inputs, dyn,
+    inv): `dyn` keeps only the keys the step's ctx reads, and `inv`
+    un-permutes the final task table (None without a presort)."""
+    dyn = dict(dyn) if dyn else {}
+    if not cfg.resilience.enabled:
+        bad = [k for k in ("throttle_inlet_c", "pdu_cap_kw",
+                           "failure_hazard_scale") if k in dyn]
+        if bad:
+            raise ValueError(
+                f"dyn key(s) {bad} belong to the resilience loop but "
+                "cfg.resilience.enabled is False: they would be silently "
+                "ignored — enable the subsystem (core/resilience.py)")
+    if weather_trace is not None:
+        dyn["wet_bulb_trace"] = weather_trace
+    if "n_active_hosts" in dyn:
+        hosts = scaling_mod.with_scale(hosts, dyn["n_active_hosts"])
+    # workload-shaping dyn keys apply to the task table itself, BEFORE the
+    # initial state, so both step executors (and any grid vmap over them)
+    # see the same typed/re-timed population
+    arrival = dyn.pop("arrival_trace", None)
+    if arrival is not None:
+        tasks = retime_task_table(tasks, arrival)
+    interactive_frac = dyn.pop("interactive_frac", None)
+    if interactive_frac is not None:
+        tasks = with_interactive_frac(
+            tasks, interactive_frac, cfg.interactive_grace_h, seed=cfg.seed)
+    # priority scheduling: permute rows into (priority desc, arrival) order
+    # ONCE, outside the scan, so the per-step priority select runs as the
+    # plain FIFO prefix (scheduler.schedule_first_fit presorted path) with
+    # no [L*T] level-major flatten+cumsum in the demand hot loop.  `inv`
+    # un-permutes the final table, so callers see original row order.
+    inv = None
+    if _presort_enabled(cfg):
+        order = priority_schedule_order(tasks, cfg.scheduler.priority_levels)
+        tasks = permute_task_table(tasks, order)
+        inv = inverse_permutation(order)
+    inputs = build_step_inputs(ci_trace, cfg, dyn=dyn)
+    dyn.pop("wet_bulb_trace", None)  # consumed by the inputs, not a ctx key
+    dyn.pop("price_trace", None)
+    dyn.pop("pv_cf_trace", None)
+    dyn.pop("pdu_cap_kw", None)  # folded into inputs.pdu_cap_kw
+    state0 = init_sim_state(tasks, hosts, dyn.get("seed", cfg.seed))
+    if cfg.probes.enabled:
+        state0 = state0._replace(
+            probes=telemetry_mod.init_probes(cfg.n_steps, cfg.probes))
+    if cfg.resilience.enabled:  # healthy start: no throttle on step 0
+        state0 = state0._replace(throttle=jnp.float32(1.0))
+    return state0, inputs, dyn, inv
 
 
 def simulate(tasks: TaskTable, hosts: HostTable, ci_trace, cfg: SimConfig,
@@ -1084,55 +1128,8 @@ def simulate(tasks: TaskTable, hosts: HostTable, ci_trace, cfg: SimConfig,
             "custom stages compose only with backend='stage-pipeline'; the "
             "megakernel fuses the default facility chain and cannot honour "
             "a replacement pipeline")
-    dyn = dict(dyn) if dyn else {}
-    if not cfg.resilience.enabled:
-        bad = [k for k in ("throttle_inlet_c", "pdu_cap_kw",
-                           "failure_hazard_scale") if k in dyn]
-        if bad:
-            raise ValueError(
-                f"dyn key(s) {bad} belong to the resilience loop but "
-                "cfg.resilience.enabled is False: they would be silently "
-                "ignored — enable the subsystem (core/resilience.py)")
-    if weather_trace is not None:
-        dyn["wet_bulb_trace"] = weather_trace
-    if "n_active_hosts" in dyn:
-        hosts = scaling_mod.with_scale(hosts, dyn["n_active_hosts"])
-    # workload-shaping dyn keys apply to the task table itself, BEFORE the
-    # initial state, so both step executors (and any grid vmap over them)
-    # see the same typed/re-timed population
-    arrival = dyn.pop("arrival_trace", None)
-    if arrival is not None:
-        from . import state as state_mod
-        tasks = state_mod.retime_task_table(tasks, arrival)
-    interactive_frac = dyn.pop("interactive_frac", None)
-    if interactive_frac is not None:
-        from . import state as state_mod
-        tasks = state_mod.with_interactive_frac(
-            tasks, interactive_frac, cfg.interactive_grace_h, seed=cfg.seed)
-    # priority scheduling: permute rows into (priority desc, arrival) order
-    # ONCE, outside the scan, so the per-step priority select runs as the
-    # plain FIFO prefix (scheduler.schedule_first_fit presorted path) with
-    # no [L*T] level-major flatten+cumsum in the demand hot loop.  The
-    # final table is un-permuted below, so callers see original row order.
-    unpermute = None
-    if _presort_enabled(cfg):
-        from . import state as state_mod
-        order = state_mod.priority_schedule_order(
-            tasks, cfg.scheduler.priority_levels)
-        tasks = state_mod.permute_task_table(tasks, order)
-        inv = state_mod.inverse_permutation(order)
-        unpermute = lambda tt: state_mod.permute_task_table(tt, inv)
-    inputs = build_step_inputs(ci_trace, cfg, dyn=dyn)
-    dyn.pop("wet_bulb_trace", None)  # consumed by the inputs, not a ctx key
-    dyn.pop("price_trace", None)
-    dyn.pop("pv_cf_trace", None)
-    dyn.pop("pdu_cap_kw", None)  # folded into inputs.pdu_cap_kw
-    state0 = init_sim_state(tasks, hosts, dyn.get("seed", cfg.seed))
-    if cfg.probes.enabled:
-        state0 = state0._replace(
-            probes=telemetry_mod.init_probes(cfg.n_steps, cfg.probes))
-    if cfg.resilience.enabled:  # healthy start: no throttle on step 0
-        state0 = state0._replace(throttle=jnp.float32(1.0))
+    state0, inputs, dyn, inv = prepare_run(tasks, hosts, ci_trace, cfg, dyn,
+                                           weather_trace)
 
     def run():
         if cfg.backend == "megakernel":
@@ -1140,8 +1137,8 @@ def simulate(tasks: TaskTable, hosts: HostTable, ci_trace, cfg: SimConfig,
         else:
             step = build_step_fn(cfg, stages, dyn)
             final, ys = jax.lax.scan(step, state0, inputs)
-        if unpermute is not None:
-            final = final._replace(tasks=unpermute(final.tasks))
+        if inv is not None:
+            final = final._replace(tasks=permute_task_table(final.tasks, inv))
         return final, ys
 
     # cut a RunRecord only for eager top-level calls: under jit/vmap (grid

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .config import SimConfig
-from .engine import simulate
+from .engine import _presort_enabled, simulate
 from . import telemetry as telemetry_mod
 from .metrics import SimResult, fleet_totals, summarize
 from .spatial import (spatial_assign, spatial_assign_online, split_by_region)
@@ -192,6 +192,31 @@ def fleet_place(tasks: TaskTable, hosts: HostTable, fleet: FleetSpec,
                           forecast_h=fleet.forecast_h)
 
 
+def _vmap_regions(fn, tasks_r: TaskTable, hosts: HostTable, cfg: SimConfig,
+                  ci_traces, wb_traces, scalar_dyn, per_region_dyn,
+                  price_traces, pv_traces):
+    """`fn(tasks, hosts, ci_trace, cfg, dyn=..., weather_trace=...)`
+    vmapped over the regions: each region's dyn holds the scalar values,
+    its own per-region values and its own price and PV traces."""
+    scalar_dyn = dict(scalar_dyn or {})
+    ci = jnp.asarray(ci_traces, jnp.float32)
+    wb, pr, pv = (None if x is None else jnp.asarray(x, jnp.float32)
+                  for x in (wb_traces, price_traces, pv_traces))
+
+    def one(tt, tr, per_r, wb_r, pr_r, pv_r):
+        dyn = {**scalar_dyn, **per_r}
+        if pr_r is not None:
+            dyn["price_trace"] = pr_r
+        if pv_r is not None:
+            dyn["pv_cf_trace"] = pv_r
+        return fn(tt, hosts, tr, cfg, dyn=dyn, weather_trace=wb_r)
+
+    in_axes = (0, 0, 0) + tuple(None if x is None else 0
+                                for x in (wb, pr, pv))
+    return jax.vmap(one, in_axes=in_axes)(
+        tasks_r, ci, dict(per_region_dyn or {}), wb, pr, pv)
+
+
 def fleet_cell(tasks_r: TaskTable, hosts: HostTable, cfg: SimConfig,
                ci_traces, wb_traces=None, scalar_dyn: dict | None = None,
                per_region_dyn: dict | None = None,
@@ -205,29 +230,11 @@ def fleet_cell(tasks_r: TaskTable, hosts: HostTable, cfg: SimConfig,
     This is the cell the grid engine vmaps — `simulate_fleet` is its
     host-side front door.
     """
-    scalar_dyn = dict(scalar_dyn or {})
-    per_region_dyn = dict(per_region_dyn or {})
-    ci = jnp.asarray(ci_traces, jnp.float32)
-    wb = (None if wb_traces is None
-          else jnp.asarray(wb_traces, jnp.float32))
-    pr = (None if price_traces is None
-          else jnp.asarray(price_traces, jnp.float32))
-    pv = (None if pv_traces is None
-          else jnp.asarray(pv_traces, jnp.float32))
+    def one(*args, **kw):
+        return summarize(simulate(*args, **kw)[0], cfg)
 
-    def one(tt, tr, per_r, wb_r, pr_r, pv_r):
-        dyn = {**scalar_dyn, **per_r}
-        if pr_r is not None:
-            dyn["price_trace"] = pr_r
-        if pv_r is not None:
-            dyn["pv_cf_trace"] = pv_r
-        final, _ = simulate(tt, hosts, tr, cfg, dyn=dyn, weather_trace=wb_r)
-        return summarize(final, cfg)
-
-    in_axes = (0, 0, 0, None if wb is None else 0, None if pr is None else 0,
-               None if pv is None else 0)
-    per = jax.vmap(one, in_axes=in_axes)(tasks_r, ci, per_region_dyn, wb, pr,
-                                         pv)
+    per = _vmap_regions(one, tasks_r, hosts, cfg, ci_traces, wb_traces,
+                        scalar_dyn, per_region_dyn, price_traces, pv_traces)
     return FleetResult(total=fleet_totals(per), per_region=per)
 
 
@@ -243,71 +250,25 @@ def _fleet_cell_spill(tasks_r: TaskTable, hosts: HostTable, cfg: SimConfig,
 
     Structure: `fleet_cell` vmaps the whole `simulate` (scan inside vmap);
     here the nesting flips to scan-of-vmapped-step so the spill hook can
-    run between steps with all regions' tables in hand.  vmap-of-scan and
-    scan-of-vmap compute the same per-region step math, and the spill is a
-    value-preserving no-op while every region is healthy, so with no
-    failures this reproduces `fleet_cell` (pinned in
-    tests/test_resilience.py).  Stage-pipeline backend only; the per-step
-    ctx mirrors `engine.build_step_fn`.
+    run between steps with all regions' tables in hand.  Everything but
+    the hook is the engine's: `simulate`'s front half
+    (`engine.prepare_run`) sets each region up and the stage pipeline's
+    step (`engine.build_step_fn`) advances it, both vmapped over regions.
+    vmap-of-scan and scan-of-vmap compute the same per-region step math,
+    and the spill is a value-preserving no-op while every region is
+    healthy, so with no failures this reproduces `fleet_cell` (pinned in
+    tests/test_resilience.py).  `simulate_fleet` admits only the
+    stage-pipeline backend here, and no priority presort: a spill moves a
+    row into the target's first INVALID slot, out of presorted order.
     """
     from . import resilience as resilience_mod
-    from . import scaling as scaling_mod
-    from .engine import (_advance_clock, build_step_inputs, default_pipeline,
-                         init_energy_flow)
-    from .state import init_sim_state
+    from .engine import build_step_fn, prepare_run
 
-    scalar_dyn = dict(scalar_dyn or {})
-    per_region_dyn = dict(per_region_dyn or {})
-    ci = jnp.asarray(ci_traces, jnp.float32)
-    wb = (None if wb_traces is None
-          else jnp.asarray(wb_traces, jnp.float32))
-    pr = (None if price_traces is None
-          else jnp.asarray(price_traces, jnp.float32))
-    pv = (None if pv_traces is None
-          else jnp.asarray(pv_traces, jnp.float32))
-
-    def prep(tt, tr, per_r, wb_r, pr_r, pv_r):
-        """Per-region init: mirrors the front half of engine.simulate."""
-        dyn = {**scalar_dyn, **per_r}
-        if wb_r is not None:
-            dyn["wet_bulb_trace"] = wb_r
-        if pr_r is not None:
-            dyn["price_trace"] = pr_r
-        if pv_r is not None:
-            dyn["pv_cf_trace"] = pv_r
-        h = hosts
-        if "n_active_hosts" in dyn:
-            h = scaling_mod.with_scale(h, dyn["n_active_hosts"])
-        inputs = build_step_inputs(tr, cfg, dyn=dyn)
-        for k in ("wet_bulb_trace", "price_trace", "pv_cf_trace",
-                  "pdu_cap_kw"):
-            dyn.pop(k, None)
-        state0 = init_sim_state(tt, h, dyn.get("seed", cfg.seed))
-        state0 = state0._replace(throttle=jnp.float32(1.0))
-        return state0, inputs, dyn
-
-    in_axes = (0, 0, 0, None if wb is None else 0, None if pr is None else 0,
-               None if pv is None else 0)
-    states0, inputs, dyn_r = jax.vmap(prep, in_axes=in_axes)(
-        tasks_r, ci, per_region_dyn, wb, pr, pv)
-
-    stages = default_pipeline(cfg)
-
-    def one_step(state, inp, dyn):
-        ctx = {"ci": inp.ci, "batt_threshold": inp.batt_threshold,
-               "ci_rising": inp.ci_rising,
-               "shift_threshold": inp.shift_threshold,
-               "wet_bulb_c": inp.wet_bulb_c, "price": inp.price,
-               "price_lo": inp.price_lo, "price_hi": inp.price_hi,
-               "pv_cf": inp.pv_cf,
-               "chiller_derate": inp.chiller_derate,
-               "pdu_cap_kw": inp.pdu_cap_kw,
-               "flow": init_energy_flow(), **dyn}
-        for stage in stages:
-            state, ctx = stage(state, ctx)
-        return _advance_clock(state, cfg)
-
-    vstep = jax.vmap(one_step)
+    states0, inputs, dyn_r, _ = _vmap_regions(
+        prepare_run, tasks_r, hosts, cfg, ci_traces, wb_traces, scalar_dyn,
+        per_region_dyn, price_traces, pv_traces)
+    vstep = jax.vmap(lambda state, inp, dyn:
+                     build_step_fn(cfg, None, dyn)(state, inp)[0])
     xs = jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), inputs)  # [S, R]
     max_spills = int(cfg.resilience.max_spills_per_step)
 
@@ -360,8 +321,8 @@ def simulate_fleet(tasks: TaskTable, hosts: HostTable, cfg: SimConfig,
                          "cfg.resilience.enabled (the spill hook reacts to "
                          "failure signals the resilience loops produce)")
     if spill:
-        # the coupled executor replays engine.build_step_fn's ctx assembly
-        # per step; features that change the scan signature are out of scope
+        # the coupled executor steps the stage pipeline; features that
+        # change the scan signature are out of scope
         if cfg.backend != "stage-pipeline":
             raise ValueError("spill_interrupted supports only the "
                              f"'stage-pipeline' backend, got {cfg.backend!r}")
@@ -372,6 +333,12 @@ def simulate_fleet(tasks: TaskTable, hosts: HostTable, cfg: SimConfig,
             if k in (dyn or {}):
                 raise ValueError(f"spill_interrupted does not support the "
                                  f"'{k}' dyn key")
+        if _presort_enabled(cfg):
+            raise ValueError("spill_interrupted does not compose with "
+                             "priority levels under first_fit: a spill "
+                             "moves a row into the target region's first "
+                             "INVALID slot, out of the presorted "
+                             "(priority, arrival) row order")
         if width is None:
             # full-width tables so every region has INVALID slots to
             # receive spilled tasks regardless of the initial placement

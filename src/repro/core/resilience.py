@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from .config import ResilienceConfig
-from .state import INVALID, PENDING, HostTable, MetricsAcc, TaskTable
+from .state import (EMPTY_TASK_ROW, INVALID, PENDING, HostTable, MetricsAcc,
+                    TaskTable)
 
 # fold_in constants decorrelating the facility processes from the host
 # failure stream (which consumes the SimState rng) and from each other
@@ -165,10 +166,11 @@ def cross_region_spill(tasks: TaskTable, hosts: HostTable,
     failure or paused by the stopper) in a region strictly less healthy
     than the healthiest one, where health = fraction of provisioned hosts
     currently up.  Each move copies the task row into the first INVALID
-    (padding) slot of the target region and invalidates the source row, so
-    task counts stay conserved; `metrics.n_spills` counts moves per source
-    region.  With no failures every region's health is 1.0, no candidate
-    qualifies, and the tables pass through with identical values.
+    (padding) slot of the target region and resets the source row to
+    `state.EMPTY_TASK_ROW`, so task counts stay conserved;
+    `metrics.n_spills` counts moves per source region.  With no failures
+    every region's health is 1.0, no candidate qualifies, and the tables
+    pass through with identical values.
     """
     act = hosts.active.astype(jnp.float32)
     up = (hosts.active & hosts.up).astype(jnp.float32)
@@ -194,21 +196,7 @@ def cross_region_spill(tasks: TaskTable, hosts: HostTable,
             return col.at[r, c].set(
                 jnp.where(do, jnp.asarray(fill, col.dtype), v))
 
-        inf, t_ = jnp.inf, tasks
-        tasks = TaskTable(
-            arrival=move(t_.arrival, inf), duration=move(t_.duration, 0),
-            remaining=move(t_.remaining, 0),
-            ckpt_remaining=move(t_.ckpt_remaining, 0),
-            cores=move(t_.cores, 0), gpus=move(t_.gpus, 0),
-            cpu_util=move(t_.cpu_util, 0), gpu_util=move(t_.gpu_util, 0),
-            status=move(t_.status, INVALID), host=move(t_.host, -1),
-            first_start=move(t_.first_start, inf),
-            finish=move(t_.finish, inf), lost_work=move(t_.lost_work, 0),
-            job_class=move(t_.job_class, 0), priority=move(t_.priority, 0),
-            shiftable=move(t_.shiftable, True),
-            sla_grace=move(t_.sla_grace, -1.0),
-            speed=move(t_.speed, 1.0),
-        )
+        tasks = jax.tree.map(move, tasks, EMPTY_TASK_ROW)
         # the moved row keeps status PENDING at the target (move() copied
         # it), so the target region's scheduler picks it up next step
         metrics = metrics._replace(

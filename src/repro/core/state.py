@@ -78,6 +78,17 @@ class TaskTable(NamedTuple):
         return self.arrival.shape[0]
 
 
+# The value of every column in an empty row: a padding row
+# (`pad_task_table`) and a row a fleet spill vacates
+# (`resilience.cross_region_spill`).
+EMPTY_TASK_ROW = TaskTable(
+    arrival=float("inf"), duration=0.0, remaining=0.0, ckpt_remaining=0.0,
+    cores=0.0, gpus=0.0, cpu_util=0.0, gpu_util=0.0, status=INVALID, host=-1,
+    first_start=float("inf"), finish=float("inf"), lost_work=0.0,
+    job_class=JOB_BATCH, priority=0, shiftable=True, sla_grace=-1.0,
+    speed=1.0)
+
+
 class HostTable(NamedTuple):
     """Host inventory.  `active` is the horizontal-scaling mask (fixed during
     a run, but it may be built from a *traced* host count — see
@@ -286,30 +297,15 @@ def stack_task_tables(tables) -> TaskTable:
 
 
 def pad_task_table(tasks: TaskTable, n: int) -> TaskTable:
-    """Pad a task table to n rows with INVALID entries (for batching)."""
+    """Pad a task table to n rows with `EMPTY_TASK_ROW` (for batching)."""
     t = tasks.n
     if t == n:
         return tasks
     assert t < n, f"cannot shrink task table {t} -> {n}"
-    k = n - t
-
-    def _pad(x, fill):
-        return jnp.concatenate([x, jnp.full((k,), fill, x.dtype)])
-
-    return TaskTable(
-        arrival=_pad(tasks.arrival, jnp.inf), duration=_pad(tasks.duration, 0),
-        remaining=_pad(tasks.remaining, 0), ckpt_remaining=_pad(tasks.ckpt_remaining, 0),
-        cores=_pad(tasks.cores, 0), gpus=_pad(tasks.gpus, 0),
-        cpu_util=_pad(tasks.cpu_util, 0), gpu_util=_pad(tasks.gpu_util, 0),
-        status=_pad(tasks.status, INVALID), host=_pad(tasks.host, -1),
-        first_start=_pad(tasks.first_start, jnp.inf),
-        finish=_pad(tasks.finish, jnp.inf), lost_work=_pad(tasks.lost_work, 0),
-        job_class=_pad(tasks.job_class, JOB_BATCH),
-        priority=_pad(tasks.priority, 0),
-        shiftable=_pad(tasks.shiftable, True),
-        sla_grace=_pad(tasks.sla_grace, -1.0),
-        speed=_pad(tasks.speed, 1.0),
-    )
+    return jax.tree.map(
+        lambda col, fill: jnp.concatenate(
+            [col, jnp.full((n - t,), fill, col.dtype)]),
+        tasks, EMPTY_TASK_ROW)
 
 
 def make_host_table(n_hosts: int, cores_per_host: float, gpus_per_host: float = 0.0,

@@ -38,7 +38,8 @@ from repro.core import resilience as resilience_mod
 from repro.core.scheduler import (schedule_aggregate, schedule_first_fit,
                                   schedule_step)
 from repro.core.shifting import forward_window_quantiles
-from repro.core.state import (INVALID, PENDING, init_metrics, pad_task_table)
+from repro.core.state import (EMPTY_TASK_ROW, INVALID, PENDING, init_metrics,
+                              pad_task_table)
 
 S = 96
 DT = 0.25
@@ -400,6 +401,32 @@ def test_cross_region_spill_moves_interrupted_task():
     assert int(np.isfinite(np.asarray(out.arrival)).sum()) == 2
 
 
+def test_padded_and_vacated_rows_are_the_empty_row():
+    """A padding row and the row a spill vacates hold `EMPTY_TASK_ROW`,
+    column by column; the moved row keeps every value it had."""
+    w = 3
+    t0 = pad_task_table(make_task_table([0.0], [2.0], [1.0], gpus=[1.0],
+                                        job_class=[2], sla_grace=[1.0]), w)
+    t0 = t0._replace(first_start=t0.first_start.at[0].set(0.5),
+                     remaining=t0.remaining.at[0].set(1.5),
+                     lost_work=t0.lost_work.at[0].set(0.25),
+                     speed=t0.speed.at[0].set(0.5))
+    tasks = _stack(t0, pad_task_table(make_task_table([0.25], [1.0], [1.0]),
+                                      w))
+    hosts = _stack(make_host_table(2, 4)._replace(up=jnp.zeros(2, bool)),
+                   make_host_table(2, 4))
+    metrics = _stack(init_metrics(), init_metrics())
+    out, m = resilience_mod.cross_region_spill(tasks, hosts, metrics, 1)
+    assert float(jnp.sum(m.n_spills)) == 1.0
+    for f in tasks._fields:
+        col, moved = np.asarray(getattr(tasks, f)), np.asarray(getattr(out, f))
+        want = np.asarray(getattr(EMPTY_TASK_ROW, f), col.dtype)
+        for r, c in ((0, 1), (0, 2), (1, 1), (1, 2)):
+            assert col[r, c] == want, f"padded row ({r}, {c}): {f}"
+        assert moved[1, 1] == col[0, 0], f"moved row: {f}"
+        assert moved[0, 0] == want, f"vacated row: {f}"
+
+
 def test_cross_region_spill_noop_when_healthy():
     w = 3
     t0 = pad_task_table(make_task_table([0.0], [2.0], [1.0]), w)
@@ -462,6 +489,28 @@ def test_fleet_spill_rescues_tasks_under_failures():
                            width=tasks.n)
     assert float(out_s.total.n_spills) > 0
     assert float(out_s.total.n_done) > float(out_p.total.n_done)
+
+
+def test_fleet_spill_rejects_priority_levels():
+    """Spill moves a row into the target region's first INVALID slot, which
+    breaks the (priority, arrival) row order the engine presorts each table
+    into under first fit with priority levels, so the pair is refused."""
+    rng = np.random.default_rng(0)
+    n = 48
+    tasks = make_task_table(np.sort(rng.uniform(0.0, 4.0, n)),
+                            rng.uniform(0.5, 3.0, n),
+                            rng.integers(1, 3, n).astype(float),
+                            job_class=rng.integers(0, 3, n))
+    res = dataclasses.replace(RES, spill_interrupted=True,
+                              chiller_mtbf_h=1e12, pdu_mtbf_h=1e12)
+    cfg = SimConfig(n_steps=S, scheduler=SchedulerConfig(priority_levels=3),
+                    resilience=res)
+    with pytest.raises(ValueError, match="priority levels"):
+        simulate_fleet(tasks, HOSTS, cfg, _fleet(3))
+    # the same fleet without spill runs
+    simulate_fleet(tasks, HOSTS, dataclasses.replace(
+        cfg, resilience=dataclasses.replace(res, spill_interrupted=False)),
+        _fleet(3))
 
 
 def test_fleet_spill_validation():
