@@ -4,13 +4,16 @@ OpenDC's scheduler walks an event queue and places each task with first-fit.
 The tensorized equivalent exploits one invariant: FIFO priority is arrival
 order, and the task table is pre-sorted by arrival, so "the next tasks to
 schedule" are simply *the first K eligible rows* — selected with a cumsum
-instead of a per-step argsort.  Placement itself is a bounded `fori_loop`
-(first-fit needs sequential core accounting); K bounds work per step and is
-exact whenever K >= eligible tasks that can start this step.
+instead of a per-step argsort.  Placement itself is a bounded sequential
+loop (first-fit needs sequential core accounting); K bounds work per step and
+is exact whenever K >= eligible tasks that can start this step.
 
 Two modes:
-  first_fit  — exact greedy placement, the production path (also available as
-               a Pallas kernel, kernels/first_fit.py).
+  first_fit  — exact greedy placement, the production path.  The placement
+               loop is one Pallas kernel launch per step on the TPU
+               (kernels/first_fit.py, through `kernels.ops.first_fit`) and
+               the `while_loop` of `first_fit_loop` on every other platform
+               and under `vmap`; both place bitwise alike.
   aggregate  — capacity-only admission that ignores per-host fragmentation;
                this reproduces the optimistic behaviour of analytical models
                the paper critiques (§III), and is also much cheaper.
@@ -47,6 +50,69 @@ def per_host_sum_form(h: int) -> str:
     if device.call_platform() == "tpu" or h <= _MATMUL_MAX_HOSTS:
         return "one_hot"
     return "segment_sum"
+
+
+def first_fit_form() -> str:
+    """The form the placement loop takes on the platform the call runs on:
+    "pallas" (one kernel launch a step, `kernels.ops.first_fit`) on the
+    TPU, else "while_loop" (`first_fit_loop`).  A call batched by `vmap`
+    runs the `while_loop` on every platform (the door's batching rule)."""
+    return "pallas" if device.call_platform() == "tpu" else "while_loop"
+
+
+def first_fit_loop(need_c, need_g, suf_c, suf_g, n_valid, free_c, free_g,
+                   usable, host_order=None):
+    """Sequential first-fit of k candidate slots onto H hosts, as a
+    `while_loop`: `(sel_host i32[k], iters i32)`, the host each slot took
+    (-1 where none) and the iterations run.  The oracle of the Pallas form.
+
+    Slots `[0, n_valid)` are the valid candidates, with needs `need_c`,
+    `need_g` and their suffix minima `suf_c`, `suf_g` (+inf past
+    `n_valid`).  The loop stops at the first slot where no usable host
+    clears those minima: every later slot would be a placement no-op (it
+    skips the candidate and changes no capacity), so the outcome is
+    bit-for-bit that of running all k.  The minima may come from different
+    candidates, so it can run past the last possible placement, but never
+    stops before one.  Under vmap the loop runs until every lane's slots
+    are done.
+
+    The body records each slot's host in a k-vector (the `[T]` table
+    writes happen once, after the loop) and updates the free capacity with
+    a select, not a scatter: `free - where(hidx == hj, take, 0.0)` applies
+    `x + (-take)` to the chosen host and `x - 0.0` (an IEEE no-op)
+    elsewhere, bit-for-bit `.at[hj].add(-take)`.
+    """
+    k = need_c.shape[0]
+    hidx = jnp.arange(free_c.shape[0], dtype=jnp.int32)
+
+    def cond(carry):
+        i, fc, fg = carry[0], carry[1], carry[2]
+        ii = jnp.minimum(i, k - 1)
+        return (i < n_valid) & jnp.any(
+            (fc >= suf_c[ii]) & (fg >= suf_g[ii]) & usable)
+
+    def body(carry):
+        i, free_c, free_g, sel_host = carry
+        need_c_i, need_g_i = need_c[i], need_g[i]
+        fits = (free_c >= need_c_i) & (free_g >= need_g_i) & usable
+        if host_order is None:
+            h = jnp.argmax(fits)        # first host that fits (first-fit)
+        else:  # first fitting host in preference order
+            h = host_order[jnp.argmax(fits[host_order])]
+        placed = fits[h]
+        hj = jnp.where(placed, h, 0).astype(jnp.int32)
+        take_c = jnp.where(placed, need_c_i, 0.0)
+        take_g = jnp.where(placed, need_g_i, 0.0)
+        free_c = free_c - jnp.where(hidx == hj, take_c, 0.0)
+        free_g = free_g - jnp.where(hidx == hj, take_g, 0.0)
+        sel_host = sel_host.at[i].set(
+            jnp.where(placed, h.astype(jnp.int32), -1))
+        return i + 1, free_c, free_g, sel_host
+
+    iters, _, _, sel_host = jax.lax.while_loop(
+        cond, body,
+        (jnp.int32(0), free_c, free_g, jnp.full((k,), -1, jnp.int32)))
+    return sel_host, iters
 
 
 def _per_host_sum(vals, seg, h: int):
@@ -203,7 +269,6 @@ def schedule_first_fit(tasks: TaskTable, hosts: HostTable, now, shift_ok,
     """
     k = cfg.slots_per_step
     t = tasks.arrival.shape[0]
-    h_n = hosts.cores.shape[0]
     scope = telemetry_mod.stage_scope
     with scope("stage_scheduler.candidates"):
         elig = _eligible(tasks, now, shift_ok)
@@ -227,7 +292,6 @@ def schedule_first_fit(tasks: TaskTable, hosts: HostTable, now, shift_ok,
     with scope("stage_scheduler.free_capacity"):
         free_c, free_g = free_capacity(tasks, hosts)
     usable = hosts.active & hosts.up
-    hidx = jnp.arange(h_n, dtype=jnp.int32)
     with scope("stage_scheduler.candidates"):
         # per-slot resource needs, gathered ONCE before the loop (the body
         # used to re-gather from the [T] columns every iteration, a batched
@@ -235,70 +299,31 @@ def schedule_first_fit(tasks: TaskTable, hosts: HostTable, now, shift_ok,
         cj = jnp.maximum(cand, 0)
         nc_all = jnp.where(cand >= 0, tasks.cores[cj], 0.0)
         ng_all = jnp.where(cand >= 0, tasks.gpus[cj], 0.0)
-        # suffix minima of the per-slot needs: once NO remaining candidate
-        # fits on ANY usable host, every later iteration is a placement
-        # no-op (it skips the candidate and changes no capacity), so the
-        # loop may stop — bit-for-bit the same outcome.  Saturated steps
-        # (full hosts behind a backlog, e.g. shifting holding a green-window
-        # burst) used to burn all k iterations doing nothing.
+        # suffix minima of the per-slot needs: the placement loop's early
+        # stop (`first_fit_loop`)
         inf32 = jnp.float32(jnp.inf)
         suf_c = jax.lax.cummin(
             jnp.where(cand >= 0, nc_all, inf32)[::-1])[::-1]
         suf_g = jax.lax.cummin(
             jnp.where(cand >= 0, ng_all, inf32)[::-1])[::-1]
-
-    # Sequential first-fit over the candidate slots, restructured for the
-    # batched (vmapped-grid) hot path:
-    #   * `while_loop` instead of `fori_loop(0, k)`: candidate lists are
-    #     -1-padded at the tail, and iterations past the first -1 were
-    #     no-ops, so stopping there is bit-for-bit the same placement.
-    #     Under vmap the loop runs until every lane's candidates are done —
-    #     the mean eligible count per step (1-2) instead of the static
-    #     bound k (64), which was the dominant per-step cost.
-    #   * the [T]-wide status/host/first_start updates leave the loop:
-    #     the body only records each slot's chosen host in a k-vector
-    #     (a dynamic-update-slice, not a scatter) and the table updates
-    #     happen ONCE after the loop.
-    #   * per-host free-capacity updates use a select instead of a scatter:
-    #     `free - take * (hidx == hj)` applies `x + (-take)` to the chosen
-    #     host and `x - 0.0` (an IEEE no-op) elsewhere, matching the old
-    #     `.at[hj].add(-take)` bit-for-bit.
-    def cond(carry):
-        i, fc, fg = carry[0], carry[1], carry[2]
-        ii = jnp.minimum(i, k - 1)
-        more = cand[ii] >= 0
-        # conservative feasibility: continue while SOME usable host clears
-        # the remaining candidates' component-wise minimum needs (the minima
-        # may come from different candidates, so this can keep iterating
-        # past the last possible placement — but it never stops before one)
-        more = more & jnp.any((fc >= suf_c[ii]) & (fg >= suf_g[ii]) & usable)
-        if slots is not None:  # masked tail, as in the fori_loop form
-            more = more & (i < slots)
-        return (i < k) & more
-
-    def body(carry):
-        i, free_c, free_g, sel_host = carry
-        ii = jnp.minimum(i, k - 1)
-        need_c, need_g = nc_all[ii], ng_all[ii]
-        fits = (free_c >= need_c) & (free_g >= need_g) & usable
-        if host_order is None:
-            h = jnp.argmax(fits)        # first host that fits (first-fit)
-        else:  # first fitting host in preference order
-            h = host_order[jnp.argmax(fits[host_order])]
-        placed = fits[h]
-        hj = jnp.where(placed, h, 0).astype(jnp.int32)
-        take_c = jnp.where(placed, need_c, 0.0)
-        take_g = jnp.where(placed, need_g, 0.0)
-        free_c = free_c - jnp.where(hidx == hj, take_c, 0.0)
-        free_g = free_g - jnp.where(hidx == hj, take_g, 0.0)
-        sel_host = sel_host.at[ii].set(
-            jnp.where(placed, h.astype(jnp.int32), -1))
-        return i + 1, free_c, free_g, sel_host
+        # the valid slots: the -1-padded candidate list is a prefix, and so
+        # is the traced slot bound's (iterations past it are no-ops)
+        valid = cand >= 0
+        if slots is not None:
+            valid = valid & (jnp.arange(k) < slots)
+        n_valid = jnp.sum(valid.astype(jnp.int32))
 
     with scope("stage_scheduler.first_fit"):
-        iters, free_c, free_g, sel_host = jax.lax.while_loop(
-            cond, body,
-            (jnp.int32(0), free_c, free_g, jnp.full((k,), -1, jnp.int32)))
+        if first_fit_form() == "pallas":
+            from repro.kernels import ops  # lazy: kernels import core
+            sel_host, iters = ops.first_fit(
+                nc_all, ng_all, suf_c, suf_g, n_valid, free_c, free_g,
+                usable, host_order)
+        else:
+            telemetry_mod.note_first_fit("while_loop")
+            sel_host, iters = first_fit_loop(
+                nc_all, ng_all, suf_c, suf_g, n_valid, free_c, free_g,
+                usable, host_order)
     with scope("stage_scheduler.commit"):
         # Deferred table writes via the INVERSE candidate map: each row's
         # slot is its rank in the admission order (csum - 1), so a [T]

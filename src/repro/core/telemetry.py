@@ -275,6 +275,7 @@ class RunRecord:
     compiles: int
     pallas_interpret: Optional[bool] = None
     per_host_sum: Optional[str] = None   # "one_hot" | "segment_sum"
+    first_fit: Optional[str] = None      # "pallas" | "while_loop"
     grid_shape: Optional[list] = None
     chunk: Optional[dict] = None    # chunk plan: predicted vs actual bytes
     mesh: Optional[dict] = None
@@ -312,6 +313,7 @@ class Telemetry:
         self.records: list = []         # RunRecords emitted this session
         self.last_pallas_interpret: Optional[bool] = None
         self.last_per_host_sum: Optional[str] = None
+        self.last_first_fit: Optional[str] = None
         self._t0 = time.perf_counter()
         self._lock = threading.Lock()
 
@@ -448,6 +450,14 @@ def note_per_host_sum(form: str) -> None:
         tel.last_per_host_sum = form
 
 
+def note_first_fit(form: str) -> None:
+    """Record the form the last placement loop took (core/scheduler.py and
+    kernels/ops.py hook)."""
+    tel = _ACTIVE
+    if tel is not None:
+        tel.last_first_fit = form
+
+
 def profile(fn, *args, logdir: Optional[str] = None, **kwargs):
     """One-command Perfetto capture: run ``fn`` under ``jax.profiler.trace``.
 
@@ -535,6 +545,7 @@ def run_recorder(kind: str, cfg: Any, **extra: Any):
         compiles=watch.count,
         pallas_interpret=interp,
         per_host_sum=tel.last_per_host_sum,
+        first_fit=tel.last_first_fit,
         memory=device_memory_watermarks(),
         grid_shape=builder.grid_shape,
         chunk=builder.chunk,

@@ -1,13 +1,31 @@
-"""First-fit placement Pallas kernel.
+"""First-fit placement as one Pallas kernel: the TPU form of the placement
+loop in `core/scheduler.schedule_first_fit`.
 
-The scheduler's inner loop is inherently sequential over candidate tasks
-(each placement changes the free-capacity vector the next decision reads),
-but fully vectorizable over hosts.  This kernel keeps the free-core/free-GPU
-vectors resident in VMEM across the whole K-candidate loop — the pure-XLA
-fori_loop version round-trips them through HBM every iteration.
+The loop is sequential over the step's candidate slots (each placement
+changes the free capacity the next slot reads) and vector-wide over hosts.
+As an XLA `while_loop` every iteration is a handful of separate device ops
+plus the loop's own handshake, ~12 us on a TPU v5e for well under a
+microsecond of vector work.  Here every slot of the step runs inside one
+launch, and the free-core and free-GPU vectors stay in VMEM throughout.
 
-Single grid cell; host vectors are padded to lanes of 128.  Candidate demands
-arrive pre-gathered as (K,) vectors; -1 rows are inert (cores = +inf).
+It computes exactly what the scheduler's `first_fit_loop` computes, with
+that loop's own arithmetic, so every placement and the iteration count are
+bitwise the loop's:
+
+  * slot i runs only while i < n_valid, the running flag has stayed up, and
+    some usable host clears the suffix minima (suf_c[i], suf_g[i]) of the
+    remaining slots' needs — the loop's early stop; the count is the number
+    of slots in which that held;
+  * the first fitting host in preference order is the fitting host of
+    least rank (natural order: rank = host index; a `host_order`
+    permutation: its inverse);
+  * `free - where(hit, need, 0.0)` is the loop's `free - where(hidx == hj,
+    take, 0.0)` on every host.
+
+Mosaic has no scalar access to VMEM and no dynamic lane reads: the slots'
+needs and the trip count sit in SMEM, read by the loop index, and slot i's
+host is written into the result row through a lane mask.  Host vectors are
+padded to `[ceil(H/128), 128]` with hosts that are never usable.
 """
 from __future__ import annotations
 
@@ -16,88 +34,93 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANE = 128
+# rows of the f32[4, k] SMEM needs block
+_NEED_C, _NEED_G, _SUF_C, _SUF_G = range(4)
 
 
-def _kernel(cores_ref, gpus_ref, freec_ref, freeg_ref,
-            assign_ref, outc_ref, outg_ref, *, k: int, h_pad: int):
-    freec = freec_ref[...]          # (rows, 128)
-    freeg = freeg_ref[...]
-    rows = freec.shape[0]
-    # flat host index per lane element, padding rows get a huge index so the
-    # argmin below never picks them (their free cores are -inf anyway)
+def _kernel(n_ref, need_ref, free_c_ref, free_g_ref, usable_ref, *rest,
+            ordered: bool):
+    if ordered:
+        rank_ref, sel_ref, iters_ref = rest
+    else:
+        sel_ref, iters_ref = rest
+    rows = free_c_ref.shape[0]
+    big = rows * _LANE                  # above every real host's rank
     hidx = (jax.lax.broadcasted_iota(jnp.int32, (rows, _LANE), 0) * _LANE
             + jax.lax.broadcasted_iota(jnp.int32, (rows, _LANE), 1))
+    rank = rank_ref[...] if ordered else hidx
+    usable = usable_ref[...] != 0
+    k_rows = sel_ref.shape[0]
+    slot = (jax.lax.broadcasted_iota(jnp.int32, (k_rows, _LANE), 0) * _LANE
+            + jax.lax.broadcasted_iota(jnp.int32, (k_rows, _LANE), 1))
+
+    def vmin(x):                        # (1, 1): stays a vector
+        return jnp.min(x, axis=(0, 1), keepdims=True)
 
     def body(i, carry):
-        freec, freeg, assign = carry
-        need_c = cores_ref[0, i]
-        need_g = gpus_ref[0, i]
-        fits = (freec >= need_c) & (freeg >= need_g)
-        cand = jnp.where(fits, hidx, h_pad)
-        first = jnp.min(cand)                 # lowest-index fitting host
-        found = first < h_pad
-        sel = (hidx == first) & found
-        freec = freec - jnp.where(sel, need_c, 0.0)
-        freeg = freeg - jnp.where(sel, need_g, 0.0)
-        assign = assign.at[0, i].set(jnp.where(found, first, -1).astype(jnp.int32))
-        return freec, freeg, assign
+        free_c, free_g, sel, run, iters = carry
+        clear = ((free_c >= need_ref[_SUF_C, i])
+                 & (free_g >= need_ref[_SUF_G, i]) & usable)
+        run = jnp.minimum(run, jnp.max(clear.astype(jnp.int32), axis=(0, 1),
+                                       keepdims=True))
+        need_c = need_ref[_NEED_C, i]
+        need_g = need_ref[_NEED_G, i]
+        fits = (free_c >= need_c) & (free_g >= need_g) & usable
+        first = vmin(jnp.where(fits, rank, big))
+        placed = (first < big) & (run > 0)
+        hit = (rank == first) & placed
+        free_c = free_c - jnp.where(hit, need_c, 0.0)
+        free_g = free_g - jnp.where(hit, need_g, 0.0)
+        host = vmin(jnp.where(hit, hidx, big)) if ordered else first
+        sel = jnp.where(slot == i, jnp.where(placed, host, -1), sel)
+        return free_c, free_g, sel, run, iters + run
 
-    assign0 = jnp.full((1, k), -1, jnp.int32)
-    freec, freeg, assign = jax.lax.fori_loop(
-        0, k, body, (freec, freeg, assign0))
-    assign_ref[...] = assign
-    outc_ref[...] = freec
-    outg_ref[...] = freeg
+    one = jnp.ones((1, 1), jnp.int32)
+    _, _, sel, _, iters = jax.lax.fori_loop(
+        0, n_ref[0], body,
+        (free_c_ref[...], free_g_ref[...],
+         jnp.full((k_rows, _LANE), -1, jnp.int32), one, 0 * one))
+    sel_ref[...] = sel
+    iters_ref[...] = jnp.broadcast_to(iters, (1, _LANE))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def first_fit_place(cand_cores, cand_gpus, free_cores, free_gpus, *,
-                    interpret: bool = True):
-    """Greedy first-fit of K candidates onto H hosts.
+def first_fit(need_c, need_g, suf_c, suf_g, n_valid, free_c, free_g, usable,
+              rank=None, *, interpret: bool = False):
+    """First-fit of k slots onto H hosts in one kernel launch.
 
-    cand_cores/cand_gpus: f32[K] demands (+inf demand = skip row).
-    free_cores/free_gpus: f32[H] current free capacity.
-    Returns (assign i32[K] host index or -1, new_free_cores, new_free_gpus).
+    need_c/need_g: f32[k] slot needs; suf_c/suf_g: f32[k] their suffix
+    minima over the valid slots (+inf past them); n_valid: i32 count of
+    valid slots (a prefix); free_c/free_g: f32[H]; usable: bool[H];
+    rank: i32[H] preference rank of each host (None: host index).
+    Returns (sel_host i32[k], -1 where nothing was placed, iters i32).
     """
-    k = cand_cores.shape[0]
-    h = free_cores.shape[0]
-    kp = max(-(-k // _LANE) * _LANE, _LANE)
-    hp = max(-(-h // _LANE) * _LANE, _LANE)
+    k = need_c.shape[0]
+    h = free_c.shape[0]
+    rows = -(-h // _LANE)
+    k_rows = -(-k // _LANE)
 
-    def padk(x):
-        return jnp.pad(jnp.asarray(x, jnp.float32), (0, kp - k),
-                       constant_values=jnp.inf).reshape(1, kp)
+    def hostvec(x, dtype, fill):
+        return jnp.pad(jnp.asarray(x, dtype), (0, rows * _LANE - h),
+                       constant_values=fill).reshape(rows, _LANE)
 
-    def padh(x):
-        return jnp.pad(jnp.asarray(x, jnp.float32), (0, hp - h),
-                       constant_values=-jnp.inf).reshape(hp // _LANE, _LANE)
-
-    kern = functools.partial(_kernel, k=kp, h_pad=hp)
-    assign, freec, freeg = pl.pallas_call(
-        kern,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((1, kp), lambda i: (0, 0)),
-            pl.BlockSpec((1, kp), lambda i: (0, 0)),
-            pl.BlockSpec((hp // _LANE, _LANE), lambda i: (0, 0)),
-            pl.BlockSpec((hp // _LANE, _LANE), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, kp), lambda i: (0, 0)),
-            pl.BlockSpec((hp // _LANE, _LANE), lambda i: (0, 0)),
-            pl.BlockSpec((hp // _LANE, _LANE), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, kp), jnp.int32),
-            jax.ShapeDtypeStruct((hp // _LANE, _LANE), jnp.float32),
-            jax.ShapeDtypeStruct((hp // _LANE, _LANE), jnp.float32),
-        ],
+    needs = jnp.stack([need_c, need_g, suf_c, suf_g]).astype(jnp.float32)
+    host_in = [hostvec(free_c, jnp.float32, 0.0),
+               hostvec(free_g, jnp.float32, 0.0),
+               hostvec(usable, jnp.int32, 0)]
+    if rank is not None:
+        host_in.append(hostvec(rank, jnp.int32, rows * _LANE))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    sel, iters = pl.pallas_call(
+        functools.partial(_kernel, ordered=rank is not None),
+        in_specs=[smem, smem] + [vmem] * len(host_in),
+        out_specs=[vmem, vmem],
+        out_shape=[jax.ShapeDtypeStruct((k_rows, _LANE), jnp.int32),
+                   jax.ShapeDtypeStruct((1, _LANE), jnp.int32)],
         interpret=interpret,
-    )(padk(cand_cores), padk(jnp.where(jnp.isinf(cand_cores), jnp.inf,
-                                       cand_gpus)),
-      padh(free_cores), padh(free_gpus))
-    return (assign.reshape(-1)[:k],
-            freec.reshape(-1)[:h],
-            freeg.reshape(-1)[:h])
+    )(jnp.reshape(n_valid, (1,)).astype(jnp.int32), needs, *host_in)
+    return sel.reshape(-1)[:k], iters[0, 0]

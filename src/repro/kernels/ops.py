@@ -1,11 +1,12 @@
 """jit'd public wrappers for the Pallas kernels (the ops layer).
 
 Each op dispatches to the Pallas kernel with the pure-jnp oracle available
-in kernels/ref.py for testing.  Interpret mode is resolved PER CALL from the
-platform the call runs on (`resolved_interpret`, which reads
-`core.device.call_platform`): on CPU the kernel body executes as traced
-jnp for validation; on TPU/GPU the real Mosaic kernel runs.  Nothing else
-selects it.
+in kernels/ref.py for testing; the placement kernel's oracle is the
+scheduler's own loop, `core.scheduler.first_fit_loop`.  Interpret mode is
+resolved PER CALL from the platform the call runs on (`resolved_interpret`,
+which reads `core.device.call_platform`): on CPU the kernel body executes
+as traced jnp for validation; on TPU/GPU the real Mosaic kernel runs.
+Nothing else selects it.
 """
 from __future__ import annotations
 
@@ -16,7 +17,7 @@ from . import first_fit as _first_fit
 from . import fused_step as _fused_step
 from . import power_carbon as _power_carbon
 from . import ssd_chunk as _ssd_chunk
-from repro.core import device, telemetry
+from repro.core import device, scheduler, telemetry
 from repro.core.config import CoolingConfig, PowerModelConfig
 
 
@@ -109,10 +110,41 @@ def facility_totals(it_kw, ci, wet_bulb_c, price, price_lo, price_hi, pv_cf,
         **kwargs)
 
 
-def first_fit_place(cand_cores, cand_gpus, free_cores, free_gpus):
-    """Greedy first-fit placement of K candidates onto H hosts."""
-    return _first_fit.first_fit_place(cand_cores, cand_gpus, free_cores,
-                                      free_gpus, interpret=resolved_interpret())
+def first_fit(need_c, need_g, suf_c, suf_g, n_valid, free_c, free_g, usable,
+              host_order=None):
+    """The scheduler's placement loop as one kernel launch
+    (kernels/first_fit.py): `(sel_host i32[k], iters i32)`, bitwise
+    `scheduler.first_fit_loop` of the same arguments.
+
+    A call batched by `vmap` runs `first_fit_loop` instead: the vmapped
+    `while_loop` runs every lane at once, for as many iterations as the
+    busiest lane needs, where the kernel batched over lanes would run them
+    one after another.  The form that ran is noted for the run record.
+    """
+    args = (need_c, need_g, suf_c, suf_g, n_valid, free_c, free_g, usable)
+    if host_order is not None:
+        args += (host_order,)
+
+    @jax.custom_batching.custom_vmap
+    def place(*args):
+        telemetry.note_first_fit("pallas")
+        rank = None
+        if host_order is not None:   # the preference order's inverse
+            *args, order = args
+            rank = jnp.zeros_like(order).at[order].set(
+                jnp.arange(order.shape[0], dtype=order.dtype))
+        return _first_fit.first_fit(*args, rank,
+                                    interpret=resolved_interpret())
+
+    @place.def_vmap
+    def _batched(axis_size, in_batched, *args):
+        telemetry.note_first_fit("while_loop")
+        out = jax.vmap(scheduler.first_fit_loop,
+                       in_axes=tuple(0 if b else None for b in in_batched),
+                       axis_size=axis_size)(*args)
+        return out, (True, True)
+
+    return place(*args)
 
 
 def ssd_intra_chunk(xdt, da, b, c):

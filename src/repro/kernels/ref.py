@@ -24,31 +24,6 @@ def fused_power_carbon(cpu_util, gpu_util, n_gpus, on, ci, dt_h, *,
     return p_kw, dc, dc * dt_h * ci / 1000.0
 
 
-def first_fit_place(cand_cores, cand_gpus, free_cores, free_gpus):
-    """Sequential greedy first-fit oracle (lax.scan over candidates)."""
-    h = free_cores.shape[0]
-    hidx = jnp.arange(h, dtype=jnp.int32)
-
-    def step(carry, need):
-        freec, freeg = carry
-        need_c, need_g = need
-        fits = (freec >= need_c) & (freeg >= need_g)
-        first = jnp.min(jnp.where(fits, hidx, h))
-        found = first < h
-        sel = (hidx == first) & found
-        freec = freec - jnp.where(sel, need_c, 0.0)
-        freeg = freeg - jnp.where(sel, need_g, 0.0)
-        out = jnp.where(found, first, -1).astype(jnp.int32)
-        return (freec, freeg), out
-
-    (freec, freeg), assign = jax.lax.scan(
-        step, (jnp.asarray(free_cores, jnp.float32),
-               jnp.asarray(free_gpus, jnp.float32)),
-        (jnp.asarray(cand_cores, jnp.float32),
-         jnp.asarray(cand_gpus, jnp.float32)))
-    return assign, freec, freeg
-
-
 def ssd_chunk(x, dt, a, b, c, chunk: int = 64):
     """Mamba-2 SSD reference: exact sequential state-space recurrence.
 

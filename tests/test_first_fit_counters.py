@@ -5,7 +5,9 @@
 placed beside the task table; the scheduler stage sums both into
 `MetricsAcc`, and `summarize` carries them into `SimResult`.  Pinned here
 against a plain NumPy re-count of the loop's rule, and across the two step
-backends, which run the same scheduler stage.
+backends, which run the same scheduler stage; and the placement kernel
+(the TPU's form, in interpret mode here) against the `while_loop` over a
+whole simulation.
 """
 from __future__ import annotations
 
@@ -13,8 +15,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (SchedulerConfig, SimConfig, make_host_table,
-                        make_task_table, simulate, summarize)
+import jax
+
+from repro.core import (FailureConfig, ResilienceConfig, SchedulerConfig,
+                        SimConfig, make_host_table, make_task_table, scheduler,
+                        simulate, summarize, telemetry)
 from repro.core.scheduler import schedule_first_fit, schedule_step
 from repro.core.state import PENDING, RUNNING
 
@@ -114,3 +119,90 @@ def test_counters_agree_between_backends_and_reach_the_summary():
     # loop ran at least once per placement
     assert float(r_s.first_fit_placed) == float(r_s.n_started)
     assert float(r_s.first_fit_iters) >= float(r_s.first_fit_placed) > 0
+
+
+def _placement_run(failures: bool):
+    """A few hundred CPU and GPU tasks on 24 hosts over two days, behind a
+    backlog; with host failures, placement follows `resilience.host_rank`."""
+    rng = np.random.default_rng(17)
+    n, steps = 300, 192
+    tasks = make_task_table(np.sort(rng.uniform(0.0, 40.0, n)),
+                            rng.uniform(0.5, 8.0, n),
+                            rng.choice([1.0, 2.0, 4.0, 8.0], n),
+                            gpus=rng.choice([0.0, 0.0, 1.0, 2.0], n))
+    hosts = make_host_table(24, 8, gpus_per_host=2)
+    ci = (300 + 100 * np.sin(np.arange(steps) / 8.0)).astype(np.float32)
+    cfg = SimConfig(n_steps=steps, backend="megakernel",
+                    scheduler=SchedulerConfig(slots_per_step=16))
+    if failures:
+        cfg = cfg.replace(
+            failures=FailureConfig(enabled=True, mtbf_h=60.0, repair_h=3.0),
+            resilience=ResilienceConfig(enabled=True, reactive_placement=True))
+    return tasks, hosts, ci, cfg
+
+
+def _forms_run(monkeypatch, out_dir, form, fn):
+    """`fn()` with the placement form forced, under a telemetry session:
+    (its result, the form the session noted last)."""
+    monkeypatch.setattr(scheduler, "first_fit_form", lambda: form)
+    with telemetry.session(out_dir=str(out_dir), export=False) as tel:
+        out = fn()
+        assert tel.last_first_fit is not None
+        return out, tel.last_first_fit
+
+
+@pytest.mark.parametrize("failures", [False, True])
+def test_placement_kernel_simulates_like_the_loop(monkeypatch, tmp_path,
+                                                  failures):
+    """Forced onto the placement kernel, a simulation's every total and
+    both loop counters are bitwise the `while_loop`'s."""
+    tasks, hosts, ci, cfg = _placement_run(failures)
+
+    def run():
+        return summarize(simulate(tasks, hosts, ci, cfg)[0], cfg)
+
+    loop, loop_form = _forms_run(monkeypatch, tmp_path, "while_loop", run)
+    kern, kern_form = _forms_run(monkeypatch, tmp_path, "pallas", run)
+    assert (loop_form, kern_form) == ("while_loop", "pallas")
+    assert float(loop.first_fit_iters) > float(loop.first_fit_placed) > 0
+    for field in loop._fields:
+        a, b = getattr(loop, field), getattr(kern, field)
+        if a is not None:
+            np.testing.assert_array_equal(np.asarray(b), np.asarray(a),
+                                          err_msg=field)
+
+
+def test_batched_placement_keeps_the_loop(monkeypatch, tmp_path):
+    """A call whose placement is batched by `vmap` (here over the traced
+    slot bound) takes the `while_loop` even where the kernel is the form,
+    and each lane places as the unbatched run does: the same counts, and
+    totals equal up to the summation order of batched float reductions
+    (bitwise on the CPU; the TPU's batched carbon sum reads 1 ulp off)."""
+    tasks, hosts, ci, cfg = _placement_run(False)
+    k = cfg.scheduler.slots_per_step
+
+    def run(slots):
+        final, _ = simulate(tasks, hosts, ci, cfg,
+                            dyn={"slots_per_step": slots})
+        return summarize(final, cfg)
+
+    single, _ = _forms_run(monkeypatch, tmp_path, "pallas",
+                           lambda: run(jnp.int32(k)))
+    lanes, form = _forms_run(
+        monkeypatch, tmp_path, "pallas",
+        lambda: jax.vmap(run)(jnp.asarray([k, k], jnp.int32)))
+    assert form == "while_loop"
+    counts = ("first_fit_iters", "first_fit_placed", "n_started", "n_done",
+              "n_decided", "n_tasks")
+    for field in single._fields:
+        a, b = getattr(single, field), getattr(lanes, field)
+        if a is None:
+            continue
+        for lane in range(2):
+            if field in counts:
+                np.testing.assert_array_equal(
+                    np.asarray(b)[lane], np.asarray(a), err_msg=field)
+            else:
+                np.testing.assert_allclose(
+                    np.asarray(b)[lane], np.asarray(a), rtol=1e-6,
+                    err_msg=field)

@@ -1,5 +1,6 @@
 """Pallas kernel correctness: every kernel sweeps shapes/dtypes against the
-pure-jnp oracle in kernels/ref.py (interpret mode on CPU)."""
+pure-jnp oracle in kernels/ref.py (interpret mode on CPU); the placement
+kernel's oracle is the scheduler's own `while_loop`."""
 from __future__ import annotations
 
 import jax
@@ -7,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import scheduler
 from repro.core.config import PowerModelConfig
 from repro.kernels import ops, ref
 from repro.kernels.ssd_chunk import ssd_intra_chunk
@@ -88,6 +90,37 @@ def test_facility_power_batched_matches_per_region_loop(r, h):
         np.testing.assert_allclose(water[i], water_i, rtol=1e-4, atol=1e-6)
 
 
+def _place_both(need_c, need_g, n_cand, free_c, free_g, usable=None,
+                slots=None, host_order=None):
+    """(kernel, loop) outputs of one placement: the Pallas kernel through
+    `ops.first_fit` (interpret mode on the CPU) and its oracle, the
+    scheduler's `while_loop`, on the inputs `schedule_first_fit` builds:
+    the first `n_cand` slots valid, the rest -1 candidates with zero needs,
+    the suffix minima over the valid ones and the valid count clipped by a
+    traced `slots`."""
+    k, h = len(need_c), len(free_c)
+    valid = np.arange(k) < n_cand
+    need_c = np.where(valid, need_c, 0.0).astype(np.float32)
+    need_g = np.where(valid, need_g, 0.0).astype(np.float32)
+    suf = [np.minimum.accumulate(np.where(valid, x, np.inf)[::-1])[::-1]
+           .astype(np.float32) for x in (need_c, need_g)]
+    n_valid = jnp.int32(min(n_cand, k if slots is None else slots))
+    usable = np.ones(h, bool) if usable is None else usable
+    free_c = np.asarray(free_c, np.float32) * usable
+    free_g = np.asarray(free_g, np.float32) * usable
+    args = [jnp.asarray(x) for x in (need_c, need_g, *suf)]
+    args += [n_valid] + [jnp.asarray(x) for x in (free_c, free_g, usable)]
+    order = None if host_order is None else jnp.asarray(host_order, jnp.int32)
+    return (ops.first_fit(*args, order),
+            scheduler.first_fit_loop(*args, order))
+
+
+def _assert_same_placement(got, want):
+    (sel, iters), (sel_w, iters_w) = got, want
+    np.testing.assert_array_equal(np.asarray(sel), np.asarray(sel_w))
+    assert int(iters) == int(iters_w)
+
+
 @pytest.mark.parametrize("k,h", [(4, 3), (16, 64), (64, 300)])
 def test_first_fit_kernel(k, h):
     rng = np.random.default_rng(k * h)
@@ -95,11 +128,83 @@ def test_first_fit_kernel(k, h):
     cand_g = rng.integers(0, 2, k).astype(np.float32)
     free_c = rng.integers(0, 16, h).astype(np.float32)
     free_g = rng.integers(0, 4, h).astype(np.float32)
-    a, fc, fg = ops.first_fit_place(cand_c, cand_g, free_c, free_g)
-    a_r, fc_r, fg_r = ref.first_fit_place(cand_c, cand_g, free_c, free_g)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(a_r))
-    np.testing.assert_allclose(fc, fc_r, atol=1e-5)
-    np.testing.assert_allclose(fg, fg_r, atol=1e-5)
+    got, want = _place_both(cand_c, cand_g, k, free_c, free_g)
+    _assert_same_placement(got, want)
+    assert (np.asarray(got[0]) >= 0).any()
+
+
+def _gpu_bound(rng, k, h):
+    """GPU hosts behind a backlog of 1-4 GPU tasks: many candidates fail."""
+    return dict(need_c=rng.integers(1, 8, k), need_g=rng.integers(1, 5, k),
+                n_cand=k, free_c=rng.integers(8, 48, h),
+                free_g=rng.integers(0, 3, h))
+
+
+def _down_hosts(rng, k, h):
+    """Down and deactivated hosts (free capacity 0) beside zero-footprint
+    tasks, which `0 >= 0` would otherwise park on them."""
+    need_c = rng.integers(0, 3, k) * (rng.uniform(size=k) < 0.5)
+    usable = rng.uniform(size=h) < 0.4
+    usable[0] = False
+    return dict(need_c=need_c, need_g=np.zeros(k), n_cand=k,
+                free_c=rng.integers(0, 4, h), free_g=np.zeros(h),
+                usable=usable)
+
+
+def _minus_one_tail(rng, k, h):
+    return dict(need_c=rng.integers(1, 8, k), need_g=np.zeros(k),
+                n_cand=k // 3, free_c=rng.integers(0, 16, h),
+                free_g=np.zeros(h))
+
+
+def _slots_below_k(rng, k, h):
+    return dict(need_c=rng.integers(1, 8, k), need_g=np.zeros(k), n_cand=k,
+                free_c=rng.integers(0, 16, h), free_g=np.zeros(h), slots=5)
+
+
+def _host_order(rng, k, h):
+    return dict(need_c=rng.integers(1, 8, k), need_g=rng.integers(0, 2, k),
+                n_cand=k, free_c=rng.integers(0, 16, h),
+                free_g=rng.integers(0, 4, h), usable=rng.uniform(size=h) < .9,
+                host_order=rng.permutation(h))
+
+
+def _saturated(rng, k, h):
+    """Nearly full hosts behind a backlog whose later half fits nowhere:
+    the suffix-minimum early stop ends the loop."""
+    room = rng.uniform(size=h) < 0.05
+    need_c = np.where(np.arange(k) < k // 2, rng.integers(4, 8, k),
+                      rng.integers(12, 16, k))
+    return dict(need_c=need_c, need_g=np.zeros(k), n_cand=k,
+                free_c=np.where(room, rng.integers(4, 8, h),
+                                rng.integers(0, 4, h)),
+                free_g=np.zeros(h))
+
+
+@pytest.mark.parametrize("h", [277, 972])
+@pytest.mark.parametrize("case", [_gpu_bound, _down_hosts, _minus_one_tail,
+                                  _slots_below_k, _host_order, _saturated],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_first_fit_kernel_matches_the_loop(case, h):
+    """The kernel places every slot and counts every iteration as the
+    scheduler's `while_loop` does, element for element."""
+    k = 64
+    rng = np.random.default_rng(h)
+    inputs = case(rng, k, h)
+    got, want = _place_both(**inputs)
+    _assert_same_placement(got, want)
+    sel, iters = np.asarray(got[0]), int(got[1])
+    n_valid = min(inputs["n_cand"], inputs.get("slots") or k)
+    assert (sel[n_valid:] == -1).all() and iters <= n_valid
+    if case is _gpu_bound:
+        assert (sel[:iters] == -1).any() and (sel[:iters] >= 0).any()
+    if case is _down_hosts:
+        placed = sel[sel >= 0]
+        assert placed.size and inputs["usable"][placed].all()
+    if case is _host_order:
+        assert (sel >= 0).any()
+    if case is _saturated:
+        assert iters < n_valid
 
 
 @pytest.mark.parametrize("shape", [(1, 2, 16, 4, 8, 1, 8),

@@ -362,7 +362,9 @@ def test_tpu_per_host_sums_simulate_like_host_form(monkeypatch):
     matmul threshold): the TPU's contraction, forced here, against the
     host's segment_sum.  Placement reads integer sums, so every task
     starts, lands and finishes identically; energy, carbon and cost read
-    the float utilization sums and move by rounding only."""
+    the float utilization sums and move by rounding only.  The TPU's
+    placement kernel runs too, interpreted: this CPU runs no Mosaic."""
+    from repro.kernels import ops as kernel_ops
     from repro.workloads.synthetic import make_workload
     tasks, hosts, _, meta = make_workload("surf", seed=3, horizon_days=2)
     assert meta["n_hosts"] == 277
@@ -384,6 +386,7 @@ def test_tpu_per_host_sums_simulate_like_host_form(monkeypatch):
     assert per_host_sum_form(277) == "segment_sum"
     host_tasks, host_res = run()
     monkeypatch.setattr(device, "call_platform", lambda: "tpu")
+    monkeypatch.setattr(kernel_ops, "resolved_interpret", lambda: True)
     assert per_host_sum_form(277) == "one_hot"
     tpu_tasks, tpu_res = run()
     assert float(host_res.n_started) > 0

@@ -528,14 +528,49 @@ class TestRunRecords:
                                                platform, n_hosts, form):
         """The form the per-host sums took is in the record: the CPU's
         matmul-or-scatter threshold, or the TPU's contraction at every host
-        count."""
+        count.  (The TPU's placement kernel runs interpreted: this CPU
+        runs no Mosaic.)"""
         from repro.core import device
+        from repro.kernels import ops
         monkeypatch.setattr(device, "call_platform", lambda: platform)
+        monkeypatch.setattr(ops, "resolved_interpret", lambda: True)
         cfg = _cfg(batt=False)
         with telemetry.session(out_dir=str(tmp_path), export=False) as tel:
             simulate(TASKS, make_host_table(n_hosts, 4), CI, cfg)
             rec = tel.records[-1]
         assert rec.per_host_sum == form
+
+    @pytest.mark.parametrize("platform,batched,form", [
+        ("cpu", False, "while_loop"), ("tpu", False, "pallas"),
+        ("tpu", True, "while_loop")])
+    def test_first_fit_form_lands_in_record(self, tmp_path, monkeypatch,
+                                            platform, batched, form):
+        """The form the placement loop took is in the record: the kernel
+        on the TPU (interpreted on this CPU), the `while_loop` elsewhere
+        and wherever placement is batched by `vmap`."""
+        from repro.core import device
+        from repro.kernels import ops
+        monkeypatch.setattr(device, "call_platform", lambda: platform)
+        monkeypatch.setattr(ops, "resolved_interpret", lambda: True)
+        cfg = _cfg(batt=False)
+
+        def run(slots):
+            final, _ = simulate(TASKS, HOSTS, CI, cfg,
+                                dyn={"slots_per_step": slots})
+            return final.metrics.first_fit_iters
+
+        k = jnp.int32(cfg.scheduler.slots_per_step)
+        with telemetry.session(out_dir=str(tmp_path), export=False) as tel:
+            if batched:
+                jax.vmap(run)(jnp.stack([k, k]))
+            else:
+                run(k)
+            rec = tel.records[-1]
+        assert rec.first_fit == form
+
+    def test_first_fit_note_is_a_no_op_without_session(self):
+        assert not telemetry.enabled()
+        telemetry.note_first_fit("pallas")  # must not raise
 
     def test_per_host_sum_note_is_a_no_op_without_session(self):
         assert not telemetry.enabled()

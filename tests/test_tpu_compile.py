@@ -8,8 +8,10 @@ attached: the widths are the SURF-calibrated datacenter (277 hosts, 124 days
 = 11,904 steps at 15 min), the Borg one (1,534 hosts: two host tiles) and
 an 8-region fleet.  The scheduler's per-host sums are compiled too, at
 the SURF task count: there they must be one contraction each, with no
-scatter; and the progress stage, which must gather no task-wide column
-from the host table.  Nothing runs, so results are checked elsewhere.
+scatter; the progress stage, which must gather no task-wide column from
+the host table; and the placement, which must be one kernel launch a step
+at the SURF, Marconi (972 hosts) and Borg widths.  Nothing runs, so
+results are checked elsewhere.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every file.
@@ -25,13 +27,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import (BatteryConfig, CoolingConfig, PricingConfig,
-                        RenewableConfig, SimConfig, device, engine,
-                        init_sim_state, make_host_table, make_task_table,
-                        scheduler)
+                        RenewableConfig, SchedulerConfig, SimConfig, device,
+                        engine, init_sim_state, make_host_table,
+                        make_task_table, scheduler, telemetry)
 from repro.core.config import PowerModelConfig
 from repro.kernels import ops
 
 SURF_HOSTS = 277
+MARCONI_HOSTS = 972
 BORG_HOSTS = 1534
 SURF_STEPS = 11904          # 124 days at dt = 0.25 h
 SURF_TASKS = 93587          # 14 days of the SURF arrival density
@@ -167,3 +170,51 @@ def test_progress_gathers_no_task_rows_from_the_host_table(one_chip, on_tpu,
     text = jax.jit(lambda s: step(s, {})[0].tasks).lower(
         state).compile().as_text()
     assert not re.findall(rf"= \w+\[{SURF_TASKS}\]\S* gather\(", text)
+
+
+def _placement_text(one_chip, tmp_path, h, t, lanes=None):
+    """The compiled text of one scheduler step (64 slots, t task rows, h
+    hosts), scopes named; `lanes` batches every input by `vmap`."""
+    tasks = _table_shapes(make_task_table([0.0], [1.0], [1.0]), t, one_chip)
+    hosts = _table_shapes(make_host_table(1, 16), h, one_chip)
+    ok = jax.ShapeDtypeStruct((t,), jnp.bool_, sharding=one_chip)
+    now = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    cfg = SchedulerConfig(slots_per_step=64)
+    fn = lambda *a: scheduler.schedule_first_fit(*a, cfg)
+    args = (tasks, hosts, now, ok)
+    if lanes is not None:
+        fn = jax.vmap(fn)
+        args = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((lanes, *x.shape), x.dtype,
+                                           sharding=one_chip), args)
+    with telemetry.session(out_dir=str(tmp_path), export=False):
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _scope_lines(text, scope):
+    """Instructions named under `scope`, bare or wrapped by a transformation
+    (`vmap(stage_scheduler.first_fit)`)."""
+    return [line for line in text.splitlines()
+            if re.search(rf'op_name="[^"]*[/(]{re.escape(scope)}[)/]', line)]
+
+
+@pytest.mark.parametrize("h", [SURF_HOSTS, MARCONI_HOSTS, BORG_HOSTS])
+def test_first_fit_compiles_to_one_kernel(one_chip, on_tpu, tmp_path, h):
+    """On the TPU the placement loop is one Pallas kernel a step: the
+    `stage_scheduler.first_fit` scope holds a Mosaic call and no `while`,
+    at every deployment width, 64 slots a step."""
+    assert scheduler.first_fit_form() == "pallas"
+    lines = _scope_lines(_placement_text(one_chip, tmp_path, h, SURF_TASKS),
+                         "stage_scheduler.first_fit")
+    assert any('custom_call_target="tpu_custom_call"' in x for x in lines)
+    assert not any(re.search(r"\bwhile\(", x) for x in lines)
+
+
+def test_batched_first_fit_compiles_on_the_loop(one_chip, on_tpu, tmp_path):
+    """Placement batched over 8 lanes keeps the `while_loop` (the door's
+    batching rule) and compiles for the chip."""
+    lines = _scope_lines(
+        _placement_text(one_chip, tmp_path, SURF_HOSTS, 4096, lanes=8),
+        "stage_scheduler.first_fit")
+    assert any(re.search(r"\bwhile\(", x) for x in lines)
+    assert not any("tpu_custom_call" in x for x in lines)
