@@ -324,14 +324,15 @@ def stage_scheduler(cfg: SimConfig) -> Stage:
              & ~shift_ok).astype(jnp.float32))
         order = (resilience_mod.host_rank(state.hosts, state.t)
                  if reactive else None)
-        tasks, iters, placed = scheduler_mod.schedule_step(
+        tasks, iters, placed, bound = scheduler_mod.schedule_step(
             state.tasks, state.hosts, state.t, shift_ok, cfg.scheduler,
             slots=ctx.get("slots_per_step"), host_order=order,
             presorted=presorted)
         m = state.metrics
         metrics = m._replace(n_shift_delays=m.n_shift_delays + n_delayed,
                              first_fit_iters=m.first_fit_iters + iters,
-                             first_fit_placed=m.first_fit_placed + placed)
+                             first_fit_placed=m.first_fit_placed + placed,
+                             slot_bound_steps=m.slot_bound_steps + bound)
         return state._replace(tasks=tasks, metrics=metrics), ctx
     return fn
 

@@ -57,9 +57,11 @@ class SimResult(NamedTuple):
     n_started: jax.Array           # tasks that ever started
     n_decided: jax.Array           # SLA denominator (done or past deadline)
     # scheduler work over the run: first-fit placement-loop iterations, and
-    # those that placed a task (0 under the aggregate mode)
+    # those that placed a task (0 under the aggregate mode); the steps in
+    # which more tasks were eligible than the placement bound took
     first_fit_iters: jax.Array
     first_fit_placed: jax.Array
+    slot_bound_steps: jax.Array
     # per-class SLA/latency metrics, indexed by the state.JOB_* codes
     # (batch, training, interactive) — the performance leg of sweeps that
     # trade carbon against latency (examples/slo_tradeoff.py).  The class
@@ -168,6 +170,7 @@ def summarize(state: SimState, cfg: SimConfig) -> SimResult:
         n_decided=jnp.sum(decided.astype(jnp.float32)),
         first_fit_iters=m.first_fit_iters,
         first_fit_placed=m.first_fit_placed,
+        slot_bound_steps=m.slot_bound_steps,
         class_sla_violation_frac=class_n_viol
         / jnp.maximum(class_n_decided, 1.0),
         class_mean_start_delay_h=class_sdelay
@@ -240,6 +243,7 @@ def fleet_totals(per_region: SimResult, axis: int = 0) -> SimResult:
         n_decided=s(p.n_decided),
         first_fit_iters=s(p.first_fit_iters),
         first_fit_placed=s(p.first_fit_placed),
+        slot_bound_steps=s(p.slot_bound_steps),
         # class fields are [R, C]: sum/recombine over the region axis,
         # keeping the trailing class axis
         class_sla_violation_frac=(s(p.class_n_violations)

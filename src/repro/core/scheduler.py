@@ -235,9 +235,11 @@ def _first_k_by_priority_reference(mask, priority, k: int, levels: int):
 def schedule_first_fit(tasks: TaskTable, hosts: HostTable, now, shift_ok,
                        cfg: SchedulerConfig, slots=None, host_order=None,
                        presorted: bool = False):
-    """Exact bounded first-fit.  Returns `(tasks, iters, placed)`: the
-    updated task table, the placement loop's iterations and the slots it
-    placed (f32 scalars, the loop's work and its useful part).
+    """Exact bounded first-fit.  Returns `(tasks, iters, placed, binds)`:
+    the updated task table, the placement loop's iterations, the slots it
+    placed (the loop's work and its useful part) and whether the placement
+    bound bound, 1 where more tasks were eligible than it let through
+    (f32 scalars).
 
     `cfg.slots_per_step` is the STATIC placement bound (it shapes the
     compiled loop).  `slots`, when given, is a TRACED per-run slot count
@@ -264,8 +266,8 @@ def schedule_first_fit(tasks: TaskTable, hosts: HostTable, now, shift_ok,
     `stage_scheduler.candidates` (eligibility, cumsum, searchsorted and the
     per-slot needs), `stage_scheduler.free_capacity` (per-host sums),
     `stage_scheduler.first_fit` (the placement loop) and
-    `stage_scheduler.commit` (the deferred `[T]` table writes, the placed
-    rows' host speed among them).
+    `stage_scheduler.commit` (the placed rows' table writes,
+    `commit_placements`).
     """
     k = cfg.slots_per_step
     t = tasks.arrival.shape[0]
@@ -281,10 +283,9 @@ def schedule_first_fit(tasks: TaskTable, hosts: HostTable, now, shift_ok,
             m = (elig[None, :] & (prio[None, :] == lvl[:, None])).reshape(-1)
         else:  # single class, or presorted rows: row order IS admission order
             m = elig
-        # One cumsum serves BOTH directions of the candidate mapping:
-        # slot -> row (cand, via k binary searches) and row -> slot (rank,
-        # via a gather) — the k-th set bit of m sits at the first position
-        # whose cumsum equals k+1, and a set row's rank is its cumsum - 1.
+        # One cumsum maps slot -> row (cand, via k binary searches: the
+        # k-th set bit of m sits at the first position whose cumsum equals
+        # k+1) and counts the eligible tasks (its last entry).
         csum = jnp.cumsum(m.astype(jnp.int32))
         wanted = jnp.arange(1, k + 1, dtype=jnp.int32)
         idx = jnp.searchsorted(csum, wanted, side="left").astype(jnp.int32)
@@ -325,46 +326,41 @@ def schedule_first_fit(tasks: TaskTable, hosts: HostTable, now, shift_ok,
                 nc_all, ng_all, suf_c, suf_g, n_valid, free_c, free_g,
                 usable, host_order)
     with scope("stage_scheduler.commit"):
-        # Deferred table writes via the INVERSE candidate map: each row's
-        # slot is its rank in the admission order (csum - 1), so a [T]
-        # gather from sel_host replaces the three [T]-target scatters this
-        # used to do — XLA CPU serializes batched scatters per lane, and
-        # they were ~half the scheduler stage's cost under vmapped grids.
-        # Rows map to at most one slot and vice versa, so the select-form
-        # updates are bitwise the scatters they replace.
-        if multi:
-            # clip is index safety only: an out-of-range priority has
-            # m[pos_t] == False (it matched no level), so it never places —
-            # exactly the original per-level behaviour
-            lvl_t = (cfg.priority_levels - 1
-                     - jnp.clip(prio, 0, cfg.priority_levels - 1))
-            pos_t = (lvl_t.astype(jnp.int32) * t
-                     + jnp.arange(t, dtype=jnp.int32))
-            rank = csum[pos_t] - 1
-            in_k = m[pos_t] & (rank < k)
-        else:
-            rank = csum - 1
-            in_k = elig & (rank < k)
-        # a placed row's host speed rides the same rank lookup as its host
-        # (from a k-entry gather of the host table): progress then reads
-        # a column, where a [T] gather from the host table took XLA's
-        # general gather emitter, ~0.7 ms a step at 93,587 rows on a v5e
-        slot_t = jnp.clip(rank, 0, k - 1)
-        host_t = sel_host[slot_t]
-        sel_speed = hosts.speed[jnp.maximum(sel_host, 0)]
-        placed_t = in_k & (host_t >= 0)
-        status = jnp.where(placed_t, RUNNING, tasks.status).astype(
-            tasks.status.dtype)
-        host = jnp.where(placed_t, jnp.maximum(host_t, 0),
-                         tasks.host).astype(tasks.host.dtype)
-        first_start = jnp.where(placed_t,
-                                jnp.minimum(tasks.first_start, now),
-                                tasks.first_start)
-        speed = jnp.where(placed_t, sel_speed[slot_t], tasks.speed)
+        tasks = commit_placements(tasks, hosts, now, cand, sel_host)
         n_placed = jnp.sum((sel_host >= 0).astype(jnp.float32))
-    return (tasks._replace(status=status, host=host, first_start=first_start,
-                           speed=speed),
-            iters.astype(jnp.float32), n_placed)
+    # the bound binds where more tasks were eligible than it let through
+    bound = k if slots is None else jnp.minimum(slots, k)
+    binds = (csum[-1] > bound).astype(jnp.float32)
+    return tasks, iters.astype(jnp.float32), n_placed, binds
+
+
+def commit_placements(tasks: TaskTable, hosts: HostTable, now, cand,
+                      sel_host):
+    """The deferred table writes of one placement pass: each slot that
+    placed (`sel_host[i] >= 0`) starts row `cand[i]` on that host.
+
+    The at most k placed rows are written by their row index, O(k) work:
+    a lookup of every row's slot (a `[T]` gather from the k-entry result,
+    or XLA's select chain over k - 1 slot masks) read and wrote all T.
+    The other slots write past the table and are dropped, so the indices
+    are unique; rows map to at most one slot.  `first_start` takes a
+    scatter-min, bitwise `minimum(first_start, now)` on the placed rows.
+    A placed row's host speed comes from a k-entry gather of the host
+    table: progress then reads a column, where a `[T]` gather from the
+    host table took XLA's general gather emitter, ~0.7 ms a step at
+    93,587 rows on a v5e.
+    """
+    t = tasks.arrival.shape[0]
+    k = cand.shape[0]
+    row = jnp.where(sel_host >= 0, cand, t + jnp.arange(k, dtype=cand.dtype))
+    at = lambda col: col.at[row]
+    opts = dict(mode="drop", unique_indices=True)
+    return tasks._replace(
+        status=at(tasks.status).set(RUNNING, **opts),
+        host=at(tasks.host).set(sel_host.astype(tasks.host.dtype), **opts),
+        first_start=at(tasks.first_start).min(now, **opts),
+        speed=at(tasks.speed).set(hosts.speed[jnp.maximum(sel_host, 0)],
+                                  **opts))
 
 
 def schedule_aggregate(tasks: TaskTable, hosts: HostTable, now, shift_ok,
@@ -410,9 +406,9 @@ def schedule_aggregate(tasks: TaskTable, hosts: HostTable, now, shift_ok,
 def schedule_step(tasks: TaskTable, hosts: HostTable, now, shift_ok,
                   cfg: SchedulerConfig, slots=None, host_order=None,
                   presorted: bool = False):
-    """One step of the configured scheduler: `(tasks, iters, placed)` as
-    `schedule_first_fit` returns them; the aggregate mode runs no placement
-    loop and counts 0 for both."""
+    """One step of the configured scheduler: `(tasks, iters, placed,
+    binds)` as `schedule_first_fit` returns them; the aggregate mode runs
+    no placement loop and counts 0 for all three."""
     if cfg.mode == "first_fit":
         return schedule_first_fit(tasks, hosts, now, shift_ok, cfg,
                                   slots=slots, host_order=host_order,
@@ -424,5 +420,6 @@ def schedule_step(tasks: TaskTable, hosts: HostTable, now, shift_ok,
                 "and cannot honor priority classes; use mode='first_fit' "
                 "with priority_levels > 1")
         zero = jnp.float32(0.0)
-        return schedule_aggregate(tasks, hosts, now, shift_ok, cfg), zero, zero
+        return (schedule_aggregate(tasks, hosts, now, shift_ok, cfg),
+                zero, zero, zero)
     raise ValueError(f"unknown scheduler mode '{cfg.mode}'")

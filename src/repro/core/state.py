@@ -137,6 +137,8 @@ class MetricsAcc(NamedTuple):
     n_spills: jax.Array        # f32[] tasks spilled to another region (fleet)
     first_fit_iters: jax.Array   # f32[] first-fit placement-loop iterations
     first_fit_placed: jax.Array  # f32[] ... of which placed a task
+    slot_bound_steps: jax.Array  # f32[] steps with more eligible tasks
+                                 #   than the placement bound
 
 
 class SimState(NamedTuple):
@@ -350,7 +352,8 @@ def init_metrics() -> MetricsAcc:
                       window_peak_kw=z, pv_energy=z, export_energy=z,
                       curtailed_energy=z, export_revenue=z, heat_reuse=z,
                       n_stops=z, throttled_h=z, derate_h=z, n_spills=z,
-                      first_fit_iters=z, first_fit_placed=z)
+                      first_fit_iters=z, first_fit_placed=z,
+                      slot_bound_steps=z)
 
 
 def init_sim_state(tasks: TaskTable, hosts: HostTable, seed: int = 0) -> SimState:

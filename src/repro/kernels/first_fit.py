@@ -23,9 +23,11 @@ bitwise the loop's:
     take, 0.0)` on every host.
 
 Mosaic has no scalar access to VMEM and no dynamic lane reads: the slots'
-needs and the trip count sit in SMEM, read by the loop index, and slot i's
-host is written into the result row through a lane mask.  Host vectors are
-padded to `[ceil(H/128), 128]` with hosts that are never usable.
+needs and the trip count sit in SMEM, read by the loop index (`f32[4, k]`,
+64 KiB of the v5e's 1 MiB at 4,096 slots), and slot i's host is written
+into its own result row, `i // 128`, through a lane mask, so a slot costs
+the same at any k.  Host vectors are padded to `[ceil(H/128), 128]` with
+hosts that are never usable.
 """
 from __future__ import annotations
 
@@ -53,15 +55,13 @@ def _kernel(n_ref, need_ref, free_c_ref, free_g_ref, usable_ref, *rest,
             + jax.lax.broadcasted_iota(jnp.int32, (rows, _LANE), 1))
     rank = rank_ref[...] if ordered else hidx
     usable = usable_ref[...] != 0
-    k_rows = sel_ref.shape[0]
-    slot = (jax.lax.broadcasted_iota(jnp.int32, (k_rows, _LANE), 0) * _LANE
-            + jax.lax.broadcasted_iota(jnp.int32, (k_rows, _LANE), 1))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANE), 1)
 
     def vmin(x):                        # (1, 1): stays a vector
         return jnp.min(x, axis=(0, 1), keepdims=True)
 
     def body(i, carry):
-        free_c, free_g, sel, run, iters = carry
+        free_c, free_g, run, iters = carry
         clear = ((free_c >= need_ref[_SUF_C, i])
                  & (free_g >= need_ref[_SUF_G, i]) & usable)
         run = jnp.minimum(run, jnp.max(clear.astype(jnp.int32), axis=(0, 1),
@@ -75,15 +75,15 @@ def _kernel(n_ref, need_ref, free_c_ref, free_g_ref, usable_ref, *rest,
         free_c = free_c - jnp.where(hit, need_c, 0.0)
         free_g = free_g - jnp.where(hit, need_g, 0.0)
         host = vmin(jnp.where(hit, hidx, big)) if ordered else first
-        sel = jnp.where(slot == i, jnp.where(placed, host, -1), sel)
-        return free_c, free_g, sel, run, iters + run
+        row = sel_ref.at[pl.ds(i // _LANE, 1), :]
+        row[...] = jnp.where(lane == i % _LANE,
+                             jnp.where(placed, host, -1), row[...])
+        return free_c, free_g, run, iters + run
 
+    sel_ref[...] = jnp.full(sel_ref.shape, -1, jnp.int32)
     one = jnp.ones((1, 1), jnp.int32)
-    _, _, sel, _, iters = jax.lax.fori_loop(
-        0, n_ref[0], body,
-        (free_c_ref[...], free_g_ref[...],
-         jnp.full((k_rows, _LANE), -1, jnp.int32), one, 0 * one))
-    sel_ref[...] = sel
+    _, _, _, iters = jax.lax.fori_loop(
+        0, n_ref[0], body, (free_c_ref[...], free_g_ref[...], one, 0 * one))
     iters_ref[...] = jnp.broadcast_to(iters, (1, _LANE))
 
 

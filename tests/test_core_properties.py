@@ -214,9 +214,9 @@ def test_presorted_schedule_matches_level_major(c):
     flatten, bit for bit, for arbitrary priority/arrival/footprint tables."""
     tasks, hosts, shift_ok, cfg = _admission_tables(c)
     now = jnp.float32(c["now"])
-    plain, _, _ = schedule_first_fit(tasks, hosts, now, shift_ok, cfg)
+    plain, *_ = schedule_first_fit(tasks, hosts, now, shift_ok, cfg)
     order = priority_schedule_order(tasks, cfg.priority_levels)
-    pre, _, _ = schedule_first_fit(permute_task_table(tasks, order), hosts,
+    pre, *_ = schedule_first_fit(permute_task_table(tasks, order), hosts,
                                    now, shift_ok[order], cfg, presorted=True)
     pre = permute_task_table(pre, inverse_permutation(order))
     for name in ("status", "host", "first_start", "remaining"):
@@ -233,7 +233,7 @@ def test_admission_is_exactly_once_and_level_ordered(c):
     tasks, _, shift_ok, cfg = _admission_tables(c)
     hosts = make_host_table(1, 10_000)  # capacity never binds
     now = jnp.float32(c["now"])
-    out, _, _ = schedule_first_fit(tasks, hosts, now, shift_ok, cfg)
+    out, *_ = schedule_first_fit(tasks, hosts, now, shift_ok, cfg)
     placed = np.asarray(out.status) == RUNNING
     elig = np.asarray(tasks.arrival) <= c["now"]
     idx = np.nonzero(elig)[0]

@@ -489,3 +489,65 @@ def test_placed_speed_column_is_the_host_speed(backend, mode, monkeypatch):
                                       np.asarray(getattr(oracle.tasks, col)),
                                       err_msg=col)
     assert np.isfinite(np.asarray(final.tasks.finish)).sum() > n_tasks // 2
+
+
+def _commit_by_rank(tasks, hosts, now, elig, sel_host, k):
+    """The commit as a `[T]` lookup of each row's slot (its rank among the
+    eligible rows) in the k-entry placement result: the oracle of
+    `scheduler.commit_placements`, whose work is O(k)."""
+    rank = jnp.cumsum(elig.astype(jnp.int32)) - 1
+    slot_t = jnp.clip(rank, 0, k - 1)
+    host_t = sel_host[slot_t]
+    sel_speed = hosts.speed[jnp.maximum(sel_host, 0)]
+    placed_t = elig & (rank < k) & (host_t >= 0)
+    return tasks._replace(
+        status=jnp.where(placed_t, RUNNING, tasks.status).astype(
+            tasks.status.dtype),
+        host=jnp.where(placed_t, jnp.maximum(host_t, 0),
+                       tasks.host).astype(tasks.host.dtype),
+        first_start=jnp.where(placed_t, jnp.minimum(tasks.first_start, now),
+                              tasks.first_start),
+        speed=jnp.where(placed_t, sel_speed[slot_t], tasks.speed))
+
+
+@pytest.mark.parametrize("k, n_tasks", [(64, 3000), (4096, 20000)])
+@pytest.mark.parametrize("n_elig", ["fewer", "more"])
+def test_commit_writes_the_placed_rows_like_the_rank_lookup(k, n_tasks,
+                                                            n_elig):
+    """The placed rows' writes by row index are bitwise the `[T]` rank
+    lookup they replace, at the engine's 64 slots and Borg's 4,096: with
+    fewer eligible rows than slots (a -1 tail) and with more (a bound that
+    binds), slots that placed nothing among them, and rows that ran once
+    before (a finite first start that `minimum` keeps)."""
+    from repro.core.scheduler import _first_k_indices, commit_placements
+    rng = np.random.default_rng(k + n_tasks)
+    tasks = make_task_table(np.sort(rng.uniform(0.0, 10.0, n_tasks)),
+                            rng.uniform(0.5, 4.0, n_tasks),
+                            rng.integers(1, 5, n_tasks).astype(float))
+    hosts = make_host_table(1534, 64, straggler_frac=0.3,
+                            straggler_speed=0.5, seed=3)
+    share = (k // 2 if n_elig == "fewer" else 2 * k) / n_tasks
+    elig = jnp.asarray(rng.uniform(size=n_tasks) < share)
+    ran = rng.uniform(size=n_tasks) < 0.2
+    tasks = tasks._replace(
+        status=jnp.where(elig, PENDING, RUNNING).astype(jnp.int32),
+        host=jnp.where(elig, -1, rng.integers(0, 1534, n_tasks)).astype(
+            jnp.int32),
+        first_start=jnp.where(ran, rng.uniform(0.0, 3.0, n_tasks),
+                              tasks.first_start).astype(jnp.float32))
+    cand = _first_k_indices(elig, k)
+    sel_host = jnp.where(
+        (cand >= 0) & jnp.asarray(rng.uniform(size=k) < 0.8),
+        jnp.asarray(rng.integers(0, 1534, k)), -1).astype(jnp.int32)
+    now = jnp.float32(5.25)
+    got = jax.jit(commit_placements)(tasks, hosts, now, cand, sel_host)
+    want = jax.jit(_commit_by_rank, static_argnums=5)(
+        tasks, hosts, now, elig, sel_host, k)
+    for col in ("status", "host", "first_start", "speed"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, col)),
+                                      np.asarray(getattr(want, col)),
+                                      err_msg=col)
+    placed = int((np.asarray(sel_host) >= 0).sum())
+    assert int((np.asarray(got.status) != np.asarray(tasks.status)).sum()) \
+        == placed > 0
+    assert (int(np.asarray(elig).sum()) > k) == (n_elig == "more")

@@ -79,11 +79,14 @@ def test_counters_match_a_numpy_recount_of_the_loop(seed, k, slots):
     tasks, hosts = _table(seed)
     now = 4.0
     cfg = SchedulerConfig(slots_per_step=k)
-    out, iters, placed = schedule_first_fit(
+    out, iters, placed, binds = schedule_first_fit(
         tasks, hosts, jnp.float32(now), jnp.ones(tasks.n, bool), cfg,
         slots=None if slots is None else jnp.int32(slots))
     want_iters, want_placed = _numpy_first_fit(tasks, hosts, now, k, slots)
     assert (float(iters), float(placed)) == (want_iters, want_placed)
+    n_elig = int(np.sum((np.asarray(tasks.status) == PENDING)
+                        & (np.asarray(tasks.arrival) <= now)))
+    assert float(binds) == float(n_elig > min(k, slots or k))
     newly = (np.asarray(out.status) == RUNNING) & \
         (np.asarray(tasks.status) == PENDING)
     assert int(newly.sum()) == want_placed
@@ -91,10 +94,10 @@ def test_counters_match_a_numpy_recount_of_the_loop(seed, k, slots):
 
 def test_aggregate_mode_counts_no_placement_loop():
     tasks, hosts = _table(0)
-    _, iters, placed = schedule_step(tasks, hosts, jnp.float32(4.0),
-                                     jnp.ones(tasks.n, bool),
-                                     SchedulerConfig(mode="aggregate"))
-    assert float(iters) == 0.0 and float(placed) == 0.0
+    _, iters, placed, binds = schedule_step(
+        tasks, hosts, jnp.float32(4.0), jnp.ones(tasks.n, bool),
+        SchedulerConfig(mode="aggregate"))
+    assert float(iters) == float(placed) == float(binds) == 0.0
 
 
 def test_counters_agree_between_backends_and_reach_the_summary():
@@ -192,7 +195,8 @@ def test_batched_placement_keeps_the_loop(monkeypatch, tmp_path):
         monkeypatch, tmp_path, "pallas",
         lambda: jax.vmap(run)(jnp.asarray([k, k], jnp.int32)))
     assert form == "while_loop"
-    counts = ("first_fit_iters", "first_fit_placed", "n_started", "n_done",
+    counts = ("first_fit_iters", "first_fit_placed", "slot_bound_steps",
+              "n_started", "n_done",
               "n_decided", "n_tasks")
     for field in single._fields:
         a, b = getattr(single, field), getattr(lanes, field)

@@ -181,14 +181,27 @@ def _saturated(rng, k, h):
                 free_g=np.zeros(h))
 
 
+CASES = pytest.mark.parametrize(
+    "case", [_gpu_bound, _down_hosts, _minus_one_tail, _slots_below_k,
+             _host_order, _saturated], ids=lambda f: f.__name__.strip("_"))
+
+
 @pytest.mark.parametrize("h", [277, 972])
-@pytest.mark.parametrize("case", [_gpu_bound, _down_hosts, _minus_one_tail,
-                                  _slots_below_k, _host_order, _saturated],
-                         ids=lambda f: f.__name__.strip("_"))
+@CASES
 def test_first_fit_kernel_matches_the_loop(case, h):
     """The kernel places every slot and counts every iteration as the
     scheduler's `while_loop` does, element for element."""
-    k = 64
+    _check_case(case, 64, h)
+
+
+@CASES
+def test_first_fit_kernel_matches_the_loop_at_borg_width(case):
+    """The same at Borg's 4,096 slots a step over its 1,534 hosts: each
+    slot writes its own row of the result, 32 rows of 128 slots."""
+    _check_case(case, 4096, 1534)
+
+
+def _check_case(case, k, h):
     rng = np.random.default_rng(h)
     inputs = case(rng, k, h)
     got, want = _place_both(**inputs)

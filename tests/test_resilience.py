@@ -77,7 +77,7 @@ def test_first_fit_skips_unusable_hosts_zero_footprint(flag):
     down-host mask is the only thing keeping the task off dead hardware."""
     hosts = make_host_table(2, 2)._replace(
         **{flag: jnp.asarray([False, True])})
-    out, _, _ = schedule_first_fit(_zero_footprint_task(), hosts,
+    out, *_ = schedule_first_fit(_zero_footprint_task(), hosts,
                                    jnp.float32(0.0), jnp.ones(1, bool),
                                    SchedulerConfig())
     assert int(out.host[0]) == 1
@@ -97,7 +97,7 @@ def test_aggregate_skips_unusable_hosts_zero_footprint(flag):
 def test_schedulers_leave_task_pending_when_no_host_usable():
     hosts = make_host_table(2, 2)._replace(up=jnp.zeros(2, bool))
     for mode in ("first_fit", "aggregate"):
-        out, _, _ = schedule_step(_zero_footprint_task(), hosts,
+        out, *_ = schedule_step(_zero_footprint_task(), hosts,
                                   jnp.float32(0.0), jnp.ones(1, bool),
                                   SchedulerConfig(mode=mode))
         assert int(out.status[0]) == PENDING, mode

@@ -10,7 +10,10 @@ an 8-region fleet.  The scheduler's per-host sums are compiled too, at
 the SURF task count: there they must be one contraction each, with no
 scatter; the progress stage, which must gather no task-wide column from
 the host table; and the placement, which must be one kernel launch a step
-at the SURF, Marconi (972 hosts) and Borg widths.  Nothing runs, so
+at the SURF, Marconi (972 hosts) and Borg widths.  At Borg's full size
+(1,131,689 task rows, 4,096 slots a step) the placement, the commit (the
+placed rows written by index, nothing gathered per task row) and the
+per-host sums (no `[H, T]` buffer) are compiled too.  Nothing runs, so
 results are checked elsewhere.
 
 The topology is described inside a fixture, never at import: only one
@@ -38,6 +41,8 @@ MARCONI_HOSTS = 972
 BORG_HOSTS = 1534
 SURF_STEPS = 11904          # 124 days at dt = 0.25 h
 SURF_TASKS = 93587          # 14 days of the SURF arrival density
+BORG_TASKS = 1131689        # 7 days of the Borg arrival density
+BORG_SLOTS = 4096           # the Borg cell's placement bound
 REGIONS = 8
 
 CPU = PowerModelConfig(80.0, 250.0, "sqrt")
@@ -172,14 +177,14 @@ def test_progress_gathers_no_task_rows_from_the_host_table(one_chip, on_tpu,
     assert not re.findall(rf"= \w+\[{SURF_TASKS}\]\S* gather\(", text)
 
 
-def _placement_text(one_chip, tmp_path, h, t, lanes=None):
-    """The compiled text of one scheduler step (64 slots, t task rows, h
+def _placement_text(one_chip, tmp_path, h, t, lanes=None, k=64):
+    """The compiled text of one scheduler step (k slots, t task rows, h
     hosts), scopes named; `lanes` batches every input by `vmap`."""
     tasks = _table_shapes(make_task_table([0.0], [1.0], [1.0]), t, one_chip)
     hosts = _table_shapes(make_host_table(1, 16), h, one_chip)
     ok = jax.ShapeDtypeStruct((t,), jnp.bool_, sharding=one_chip)
     now = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
-    cfg = SchedulerConfig(slots_per_step=64)
+    cfg = SchedulerConfig(slots_per_step=k)
     fn = lambda *a: scheduler.schedule_first_fit(*a, cfg)
     args = (tasks, hosts, now, ok)
     if lanes is not None:
@@ -218,3 +223,39 @@ def test_batched_first_fit_compiles_on_the_loop(one_chip, on_tpu, tmp_path):
         "stage_scheduler.first_fit")
     assert any(re.search(r"\bwhile\(", x) for x in lines)
     assert not any("tpu_custom_call" in x for x in lines)
+
+
+def test_borg_placement_and_commit_compile_at_published_width(
+        one_chip, on_tpu, tmp_path):
+    """At Borg's width (1,534 hosts, 1,131,689 task rows, 4,096 slots a
+    step) placement is still one kernel launch a step, and the commit
+    writes the placed rows by index: no gather of task rows from the
+    k-entry result and no select chain over k slot masks, nothing
+    task-wide but the four scattered columns."""
+    text = _placement_text(one_chip, tmp_path, BORG_HOSTS, BORG_TASKS,
+                           k=BORG_SLOTS)
+    first_fit = _scope_lines(text, "stage_scheduler.first_fit")
+    assert sum('custom_call_target="tpu_custom_call"' in x
+               for x in first_fit) == 1
+    assert not any(re.search(r"\bwhile\(", x) for x in first_fit)
+    commit = _scope_lines(text, "stage_scheduler.commit")
+    task_wide = rf"= \w+\[{BORG_TASKS}\]\S* (\w[\w-]*)\("
+    ops = {m.group(1) for x in commit for m in [re.search(task_wide, x)] if m}
+    assert not ops & {"gather", "select"}, ops
+    assert sum(bool(re.search(r"\bselect\(", x)) for x in commit) < 8
+    assert sum(bool(re.search(r"\bscatter\(", x)) for x in commit) == 4
+
+
+@pytest.mark.parametrize("fn", [scheduler.free_capacity,
+                                scheduler.host_utilization])
+def test_borg_per_host_sums_hold_no_host_by_task_buffer(one_chip, on_tpu,
+                                                         fn):
+    """The per-host sums at Borg's 1,534 hosts x 1,131,689 task rows: one
+    contraction, its one-hot never written (an `[H, T]` buffer would take
+    6.9 GB as f32)."""
+    tasks = _table_shapes(make_task_table([0.0], [1.0], [1.0]), BORG_TASKS,
+                          one_chip)
+    hosts = _table_shapes(make_host_table(1, 16), BORG_HOSTS, one_chip)
+    exe = jax.jit(fn).lower(tasks, hosts).compile()
+    assert len(re.findall(r"\b(?:convolution|dot)\(", exe.as_text())) == 1
+    assert exe.memory_analysis().temp_size_in_bytes < 4 * BORG_TASKS

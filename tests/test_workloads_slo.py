@@ -483,9 +483,9 @@ class TestSinglePassScheduler:
         tasks, cfg, now = self._case(seed)
         hosts = make_host_table(int(seed % 3) + 1, 4)
         ok = jnp.ones(tasks.n, bool)
-        plain, _, _ = schedule_first_fit(tasks, hosts, now, ok, cfg)
+        plain, *_ = schedule_first_fit(tasks, hosts, now, ok, cfg)
         order = priority_schedule_order(tasks, cfg.priority_levels)
-        pre, _, _ = schedule_first_fit(permute_task_table(tasks, order),
+        pre, *_ = schedule_first_fit(permute_task_table(tasks, order),
                                        hosts, now, ok[order], cfg,
                                        presorted=True)
         pre = permute_task_table(pre, inverse_permutation(order))
@@ -501,7 +501,7 @@ class TestSinglePassScheduler:
         most once, higher classes never displaced by lower ones."""
         tasks, cfg, now = self._case(seed)
         hosts = make_host_table(1, 10_000)  # capacity never binds
-        out, _, _ = schedule_first_fit(tasks, hosts, now,
+        out, *_ = schedule_first_fit(tasks, hosts, now,
                                        jnp.ones(tasks.n, bool), cfg)
         placed = np.asarray(out.status) == RUNNING
         elig = np.asarray(tasks.arrival) <= float(now)
